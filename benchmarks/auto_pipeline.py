@@ -129,7 +129,7 @@ def _measure_timing(scfg, times, reps=20):
     proc = subprocess.run(
         [_sys.executable, "-c", _TIMING_SCRIPT, _json.dumps(spec)],
         capture_output=True, text=True, timeout=600,
-        env={**_os.environ,
+        env={**_os.environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src:" + _os.environ.get("PYTHONPATH", "")})
     if proc.returncode != 0:
         err = (proc.stderr.strip().splitlines() or ["unknown"])[-1][:100]
@@ -153,7 +153,7 @@ def _measure_hlo(scfg, times, wire):
     proc = subprocess.run(
         [_sys.executable, "-c", _HLO_SCRIPT, _json.dumps(spec)],
         capture_output=True, text=True, timeout=600,
-        env={**_os.environ,
+        env={**_os.environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src:" + _os.environ.get("PYTHONPATH", "")})
     if proc.returncode != 0:
         err = (proc.stderr.strip().splitlines() or ["unknown"])[-1][:100]

@@ -62,7 +62,7 @@ print(f"RESULT wave_us={t_wave*1e6:.0f} base_us={t_base*1e6:.0f} "
 
 
 def run() -> list[str]:
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")   # simulated devices
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     res = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

@@ -52,10 +52,6 @@ def _drill(name: str, faults: str, tmp: str):
 
 
 def run(json_sink: dict | None = None) -> list[str]:
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          tempfile.mkdtemp(prefix="repro_rec_cache_"))
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "1")
     tmp = tempfile.mkdtemp(prefix="repro_rec_")
     rows = []
     sink = {} if json_sink is None else json_sink.setdefault("recovery", {})

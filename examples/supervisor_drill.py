@@ -157,9 +157,6 @@ def main():
         ap.error(f"unknown scenario(s) {unknown}; choose from "
                  f"{list(SCENARIOS)}")
     names = args.scenarios or (FAST if args.fast else list(SCENARIOS))
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          tempfile.mkdtemp(prefix="repro_supx_cache_"))
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     tmp = (os.environ.get("SUPERVISOR_DRILL_DIR")
            or tempfile.mkdtemp(prefix="repro_supx_"))
     os.makedirs(tmp, exist_ok=True)

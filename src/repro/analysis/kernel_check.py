@@ -209,12 +209,12 @@ def check_gated_linear_scan(R: int, T: int, C: int, *,
     return c.report()
 
 
-# ---- ops-layer fallback predicates (errors-only booleans) ----------------
+# ---- ops-layer launch predicates (errors-only booleans) ------------------
 
 def skip_concat_matmul_supported(rows: int, d: int, n: int,
                                  block: int = 128) -> bool:
-    """Whether (rows, D) x (2D, N) operands tile the kernel's grid —
-    the ops-layer fallback predicate (reference contraction otherwise)."""
+    """Whether (rows, D) x (2D, N) operands tile the kernel's grid (the
+    check ``models.diffusion._skip_project`` raises on)."""
     return check_skip_concat_matmul(rows, d, n, block_m=block,
                                     block_n=block, block_k=block).ok
 

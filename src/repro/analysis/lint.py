@@ -5,10 +5,9 @@ Three rules, each born from a real breakage mode in this codebase:
 - **compat-only-experimental** — ``jax.experimental`` (and
   ``shard_map`` in particular) may be imported ONLY in
   ``runtime/compat.py``: jax moves experimental APIs between releases
-  (``jax.experimental.shard_map`` -> ``jax.sharding``), and the compat
-  shim is where the version probe lives.  The Pallas kernels are exempt —
-  ``jax.experimental.pallas`` *is* their API surface and they are
-  already isolated behind interpret-mode fallbacks.
+  (``jax.experimental.shard_map`` -> ``jax.shard_map``), and one module
+  absorbs the next move.  The Pallas kernels are exempt —
+  ``jax.experimental.pallas`` *is* their API surface.
 - **core-lazy-jax** — no module-top ``jax`` import anywhere under
   ``core/``: the planning layer (partitioner, scheduler, cost models) is
   pure numpy/python by design, importable in schedulers, CI linters and
@@ -40,7 +39,6 @@ RULES = ("compat-only-experimental", "core-lazy-jax",
 #: the compat shim itself, plus runtime/sharding.py (the PartitionSpec
 #: rule tables sit next to the sharding entry points it re-exports)
 COMPAT_MODULES = ("runtime/compat.py", "runtime/sharding.py")
-COMPAT_MODULE = COMPAT_MODULES[0]   # back-compat alias
 #: subtrees exempt from the compat rule (pallas IS the kernel API)
 KERNEL_PREFIX = "kernels/"
 

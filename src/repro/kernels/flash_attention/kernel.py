@@ -33,10 +33,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 
     def body(ki, carry):
         acc, m_prev, l_prev = carry
-        k = pl.load(k_ref, (pl.dslice(ki * block_k, block_k),
-                            pl.dslice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(ki * block_k, block_k),
-                            pl.dslice(None))).astype(jnp.float32)
+        rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        k = k_ref[rows, :].astype(jnp.float32)
+        v = v_ref[rows, :].astype(jnp.float32)
         s = q @ k.T                                     # (bq, bk)
         q_pos = qi * q_block + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)

@@ -14,10 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import attention_reference
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.backend import use_interpret
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -35,7 +32,7 @@ def flash_attention(q, k, v, causal=True, window=None,
     out = flash_attention_fwd(qf, kf, vf, causal=causal, window=window,
                               block_q=min(block_q, S),
                               block_k=min(block_k, T),
-                              interpret=_use_interpret())
+                              interpret=use_interpret())
     return out.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)
 
 

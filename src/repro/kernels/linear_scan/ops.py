@@ -12,16 +12,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.linear_scan.kernel import gated_linear_scan_fwd
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.backend import use_interpret
 
 
 @jax.custom_vjp
 def gated_linear_scan(a, x):
     """a, x: (R, T, C) -> h: (R, T, C) with h_t = a_t*h_{t-1} + x_t."""
-    return gated_linear_scan_fwd(a, x, interpret=_use_interpret())
+    return gated_linear_scan_fwd(a, x, interpret=use_interpret())
 
 
 def _fwd(a, x):

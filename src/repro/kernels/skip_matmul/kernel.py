@@ -25,12 +25,12 @@ def _kernel(h_ref, s_ref, w1_ref, w2_ref, o_ref, *, block_k: int, K: int):
     nk = K // block_k
 
     def body(ki, acc):
-        sl = pl.dslice(ki * block_k, block_k)
-        h = pl.load(h_ref, (pl.dslice(None), sl)).astype(jnp.float32)
-        s = pl.load(s_ref, (pl.dslice(None), sl)).astype(jnp.float32)
-        w1 = pl.load(w1_ref, (sl, pl.dslice(None))).astype(jnp.float32)
-        w2 = pl.load(w2_ref, (sl, pl.dslice(None))).astype(jnp.float32)
-        return acc + h @ w1 + s @ w2
+        sl = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        acc += jnp.dot(h_ref[:, sl], w1_ref[sl, :],
+                       preferred_element_type=jnp.float32)
+        acc += jnp.dot(s_ref[:, sl], w2_ref[sl, :],
+                       preferred_element_type=jnp.float32)
+        return acc
 
     acc = jax.lax.fori_loop(0, nk, body,
                             jnp.zeros((bm, bn), jnp.float32))

@@ -7,17 +7,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.skip_matmul.kernel import skip_concat_matmul_fwd
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.backend import use_interpret
 
 
 # Single source of truth for the launch constraints lives in the static
 # analysis layer (repro.analysis.kernel_check, jax-free): each dim must
 # be a positive multiple of its clamped block size and the VMEM-resident
-# blocks must fit the core budget.  Callers use the predicate to fall
-# back to the reference contraction instead of tripping the kernel's
+# blocks must fit the core budget.  Callers check the predicate and
+# reject shapes that do not tile before reaching the kernel's
 # trace-time assert.
 from repro.analysis.kernel_check import skip_concat_matmul_supported  # noqa: F401
 
@@ -29,7 +26,7 @@ def skip_concat_matmul(h, s, w):
     D = shape[-1]
     hf = h.reshape(-1, D)
     sf = s.reshape(-1, D)
-    out = skip_concat_matmul_fwd(hf, sf, w, interpret=_use_interpret())
+    out = skip_concat_matmul_fwd(hf, sf, w, interpret=use_interpret())
     return out.reshape(*shape[:-1], w.shape[1])
 
 

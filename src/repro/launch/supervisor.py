@@ -200,6 +200,9 @@ class Supervisor:
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = src + os.pathsep * bool(env.get("PYTHONPATH")) \
             + env.get("PYTHONPATH", "")
+        # each worker simulates one host's devices on the CPU; on a machine
+        # with a TPU it must not reach for the chip the parent may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                             f"{dp * pp}")
         env.pop("REPRO_FAULTS", None)   # faults go through the CLI only

@@ -50,7 +50,9 @@ one subprocess per host):
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch uvit --steps 200
     PYTHONPATH=src python -m repro.launch.train --arch uvit --pipeline \
-        --devices 8 --steps 50          # wave PP over 8 simulated devices
+        --devices 8 --dp 2 --steps 50   # wave PP over 8 simulated devices
+    PYTHONPATH=src python -m repro.launch.train --arch uvit-h --pipeline \
+        --layers 8 --steps 10           # UViT-H widths on the devices present
 """
 import argparse
 import dataclasses
@@ -58,12 +60,21 @@ import json
 import os
 from typing import Any
 
+#: parameter init uses PRNGKey(SEED) and step k's timesteps and noise use
+#: fold_in(PRNGKey(SEED), k) — what a reference run rebuilds to compare
+SEED = 0
+#: ``--arch`` values of the pipeline path (see :func:`pipeline_config`)
+PIPELINE_ARCHS = ("uvit", "skipvit", "uvit-nano", "uvit-h")
+
 
 def _parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="uvit",
-                    help="smoke arch key (see repro.configs.smoke) or "
-                         "'uvit'/'skipvit' for the pipeline path")
+                    help="smoke arch key (see repro.configs.smoke); with "
+                         f"--pipeline one of {PIPELINE_ARCHS}")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="pipeline path: depth cut (total enc + dec "
+                         "blocks) of --arch uvit-h; widths stay published")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--global-batch", type=int, default=16)
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -73,9 +84,12 @@ def _parse_args(argv=None):
                     help="checkpoint retention (verified-complete steps)")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--pipeline", action="store_true",
-                    help="wave pipeline over simulated devices")
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--dp", type=int, default=2,
+                    help="train through auto_pipeline on a (dp, pp) mesh")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="simulate this many devices on the CPU backend "
+                         "(sets JAX_PLATFORMS=cpu); default: the devices "
+                         "JAX finds")
+    ap.add_argument("--dp", type=int, default=1,
                     help="data-parallel degree of the (data, model) mesh")
     ap.add_argument("--pp", type=int, default=None,
                     help="pipeline degree (default: devices // dp)")
@@ -114,6 +128,9 @@ def _parse_args(argv=None):
                          "step commit")
     ap.add_argument("--simulate-failure", type=int, default=0,
                     help="legacy alias for --faults kill@K")
+    ap.add_argument("--logical-params", action="store_true",
+                    help="return the merged model-space params in the "
+                         "TrainResult (copies every weight to the host)")
     ap.add_argument("--out-json", default=None,
                     help="write the step->loss trajectory + resume "
                          "metadata here on exit")
@@ -137,8 +154,16 @@ class TrainResult:
     losses: dict                    # step -> float (host)
     start: int                      # first step this invocation ran
     resumed: Any = None             # RestoreInfo | None
-    logical_params: Any = None      # model-space params (plan-independent)
+    logical_params: Any = None      # model-space params (plan-independent;
+    #                                 only with --logical-params)
     skipped_steps: int = 0          # non-finite updates the guard skipped
+    step_s: dict = dataclasses.field(default_factory=dict)
+    #                                 step -> seconds, host clock around the
+    #                                 step up to its loss on the host
+    compile_s: float | None = None  # pipeline path: step-program compile
+    plan: dict | None = None        # pipeline path: compiled.state_spec()
+    device_bytes: list | None = None  # bytes_in_use per local device with
+    #                                   the training state still live
 
 
 def main(argv=None):
@@ -163,23 +188,28 @@ def run(args) -> TrainResult:
         if args.heartbeat_dir:
             write_heartbeat(args.heartbeat_dir, Heartbeat(
                 args.host_id, step, phase, loss=loss, grad_norm=gnorm,
-                step_s=step_s, gen=args.gen))
+                step_s=step_s, gen=args.gen,
+                start=start if phase == "train" else None))
 
     beat(-1, "init")
-    if args.pipeline and "XLA_FLAGS" not in os.environ:
-        need = max(args.devices,
-                   args.dp * (args.pp or max(args.devices // args.dp, 1)))
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={need}")
+    if args.pipeline and args.devices:
+        # simulated devices live on the CPU backend: never reach for (or
+        # silently fall back from) an accelerator for them
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
 
     import jax
 
     from repro.checkpoint import CheckpointManager, latest_step, \
         restore_checkpoint
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.optim import AdamWConfig, cosine_schedule
 
+    print(f"[train] compile cache: {enable_compile_cache()}")
     opt_cfg = AdamWConfig(lr=args.lr)
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(SEED)
 
     if args.pipeline:
         params, opt_state, step_fn, loader, pack, compiled = \
@@ -198,8 +228,8 @@ def run(args) -> TrainResult:
     multi_host = args.num_hosts > 1
     if multi_host:
         from repro.launch.mesh import FileBarrier, HostTopology
-        topo = HostTopology(args.num_hosts,
-                            max(args.devices // args.num_hosts, 1))
+        topo = HostTopology(args.num_hosts, max(
+            (args.devices or len(jax.devices())) // args.num_hosts, 1))
         print("[train] " + topo.describe().replace("\n", "\n[train] "))
         if args.heartbeat_dir:
             barrier = FileBarrier(
@@ -226,16 +256,21 @@ def run(args) -> TrainResult:
 
     guard = GradGuard(budget=args.nan_skip_budget)
     losses: dict[int, float] = {}
+    step_times: dict[int, float] = {}
 
     def finish(loss) -> TrainResult:
         beat(args.steps, "done")
         logical = None
-        if compiled is not None:
+        if compiled is not None and args.logical_params:
             logical = jax.device_get(compiled.merge_params(*params))
         res = TrainResult(
             final_loss=None if loss is None else float(loss),
             losses=losses, start=start, resumed=resumed,
-            logical_params=logical, skipped_steps=guard.skipped_total)
+            logical_params=logical, skipped_steps=guard.skipped_total,
+            step_s=step_times, compile_s=getattr(step_fn, "compile_s", None),
+            plan=compiled.state_spec() if compiled is not None else None,
+            device_bytes=[(d.memory_stats() or {}).get("bytes_in_use")
+                          for d in jax.local_devices()])
         if args.out_json:
             doc = {"final_loss": res.final_loss,
                    "losses": {str(k): v for k, v in losses.items()},
@@ -308,6 +343,7 @@ def run(args) -> TrainResult:
                 raise SystemExit(EXIT_ESCALATE) from None
             raise
         losses[step] = float(loss)
+        step_times[step] = time.time() - t_step
         if args.out_json:
             # incremental (atomic) trajectory dump: a worker killed or
             # torn down mid-run still leaves its losses for the
@@ -401,100 +437,204 @@ def _build_smoke_trainer(args, key, opt_cfg):
     return params, opt_state, step_fn, loader, pack
 
 
-def _pipeline_mesh(dp: int, pp: int):
-    """(data, model) mesh; prefix-slice when the host exposes more
-    devices than the plan needs (the shrink-restore drill resumes a
-    P=1 x dp=2 plan inside a process forced to 8 host devices)."""
-    import jax
-    try:
-        return jax.make_mesh((dp, pp), ("data", "model"))
-    except ValueError:
-        import numpy as np
-        from jax.sharding import Mesh
-        devs = jax.devices()
-        if len(devs) < dp * pp:
-            raise
-        return Mesh(np.asarray(devs[:dp * pp]).reshape(dp, pp),
-                    ("data", "model"))
+def pipeline_config(arch: str, layers: int | None = None):
+    """Model config of a pipeline-path ``--arch``.
 
-
-def _build_pipeline_trainer(args, key, opt_cfg):
-    """Wave-PP trainer on simulated host devices via the PULSE compile
-    path: graph -> partition -> schedule -> executor (runtime.compile).
-
-    The model architecture is FIXED (independent of the mesh shape) so a
+    The small archs are fixed (independent of the mesh shape) so a
     checkpoint from one (pp, dp, zero, V) plan restores elastically onto
-    any other.
+    any other; they keep the multi-device CPU drills fast.  ``uvit-h`` is
+    the paper's UViT-H (``configs/uvit_h.CFG``) at its published widths;
+    ``layers`` cuts its depth only.
     """
-    import jax
-    import jax.numpy as jnp
-    from repro.models.diffusion import (SkipViTConfig, UViTConfig,
-                                        skipvit_pipeline_graph,
-                                        uvit_pipeline_graph)
-    from repro.runtime.compile import auto_pipeline
-    from repro.runtime.adapters import (diffusion_model_fns,
-                                        make_diffusion_microbatches,
-                                        skipvit_model_fns)
-    from repro.runtime.resilience import all_finite
-    from repro.optim import adamw_init, adamw_update
-    from repro.data import SyntheticLatentDataset, ShardedLoader
-
-    dp = args.dp
-    P = args.pp or max(args.devices // dp, 1)
-    mesh = _pipeline_mesh(dp, P)
-    M = args.microbatches
-    if args.arch == "skipvit":
-        cfg = SkipViTConfig("skipvit-pp", img_size=8, in_ch=4, patch=2,
-                            d_model=64, n_heads=4, d_ff=128, n_classes=10,
-                            n_enc=4, n_mid=2, n_dec=4)
-        graph = skipvit_pipeline_graph(cfg, batch=args.global_batch // M)
-        fns = skipvit_model_fns(cfg)
-    elif args.arch == "uvit-nano":
+    from repro.models.diffusion import SkipViTConfig, UViTConfig
+    if arch == "uvit-h":
+        from repro.configs.uvit_h import CFG
+        if layers is None:
+            return CFG
+        if layers < 2 or layers % 2:
+            raise ValueError(f"--layers {layers}: UViT-H needs an even "
+                             "block count (half encoder, half decoder)")
+        return dataclasses.replace(CFG, n_layers=layers)
+    if layers is not None:
+        raise ValueError("--layers cuts the depth of --arch uvit-h only")
+    if arch == "skipvit":
+        return SkipViTConfig("skipvit-pp", img_size=8, in_ch=4, patch=2,
+                             d_model=64, n_heads=4, d_ff=128, n_classes=10,
+                             n_enc=4, n_mid=2, n_dec=4)
+    if arch == "uvit-nano":
         # smallest arch that still pipelines: keeps the multi-process
         # supervisor drill inside a CI time budget on a 1-core box
-        cfg = UViTConfig("uvit-nano", img_size=8, in_ch=4, patch=4,
-                         d_model=32, n_layers=8, n_heads=2, d_ff=64,
-                         n_classes=10)
-        graph = uvit_pipeline_graph(cfg, batch=args.global_batch // M)
-        fns = diffusion_model_fns(cfg, "uvit")
+        return UViTConfig("uvit-nano", img_size=8, in_ch=4, patch=4,
+                          d_model=32, n_layers=8, n_heads=2, d_ff=64,
+                          n_classes=10)
+    if arch == "uvit":
+        return UViTConfig("uvit-pp", img_size=8, in_ch=4, patch=2,
+                          d_model=64, n_layers=8, n_heads=4, d_ff=128,
+                          n_classes=10)
+    raise ValueError(f"unknown pipeline arch {arch!r}; choose from "
+                     f"{PIPELINE_ARCHS}")
+
+
+def pipeline_loader(cfg, global_batch: int):
+    """The pipeline path's data: class-conditioned synthetic latents at
+    the config's resolution, channels and class count."""
+    from repro.data import ShardedLoader, SyntheticLatentDataset
+    ds = SyntheticLatentDataset(img_size=cfg.img_size, channels=cfg.in_ch,
+                                n_classes=cfg.n_classes)
+    return ShardedLoader(ds, global_batch=global_batch)
+
+
+def _pipeline_mesh(dp: int, pp: int):
+    """(data, model) mesh over the first ``dp * pp`` devices JAX finds.
+    A plan wider than the devices present fails here."""
+    import jax
+    devs = jax.devices()
+    if dp * pp > len(devs):
+        raise ValueError(
+            f"the plan needs dp x pp = {dp} x {pp} = {dp * pp} devices but "
+            f"JAX finds {len(devs)} ({devs[0].platform}); lower --dp/--pp "
+            "or simulate devices on the CPU with --devices")
+    return jax.make_mesh((dp, pp), ("data", "model"),
+                         devices=devs[:dp * pp])
+
+
+def _memory_line(exe) -> str:
+    ma = exe.memory_analysis()
+    if ma is None:
+        return "memory analysis unavailable"
+    gib = lambda b: f"{b / 2 ** 30:.2f} GiB"
+    return (f"arguments {gib(ma.argument_size_in_bytes)}, outputs "
+            f"{gib(ma.output_size_in_bytes)} (aliased "
+            f"{gib(ma.alias_size_in_bytes)}), temporaries "
+            f"{gib(ma.temp_size_in_bytes)}")
+
+
+class _CompiledStep:
+    """A jitted train step compiled ahead of its first call — timed apart
+    from the step, with its memory analysis printed — and called with its
+    inputs placed as the program reads them (a no-op for arrays already in
+    place; places restored state and host-made batches).  The compile
+    still happens inside step 0, where a supervisor's watchdog expects
+    warm-up."""
+
+    def __init__(self, jitted, shardings):
+        self.jitted, self.shardings = jitted, shardings
+        self.exe = None
+        self.compile_s: float | None = None
+
+    def __call__(self, *args):
+        import time
+
+        import jax
+        args = jax.device_put(args, self.shardings)
+        if self.exe is None:
+            t0 = time.time()
+            self.exe = self.jitted.lower(*args).compile()
+            self.compile_s = time.time() - t0
+            print(f"[train] step program compiled in {self.compile_s:.1f}"
+                  f" s: {_memory_line(self.exe)}")
+        return self.exe(*args)
+
+
+def pipeline_plan(args):
+    """``(cfg, compiled)``: the ``--arch`` config and its auto_pipeline
+    plan for ``--dp`` x ``--pp`` (default pp: the devices JAX finds over
+    dp), ZeRO stage, interleave, microbatches and wire dtype."""
+    import jax
+
+    from repro.models.diffusion import (SkipViTConfig,
+                                        skipvit_pipeline_graph,
+                                        uvit_pipeline_graph)
+    from repro.runtime.adapters import diffusion_model_fns, \
+        skipvit_model_fns
+    from repro.runtime.compile import auto_pipeline
+
+    cfg = pipeline_config(args.arch, args.layers)
+    dp = args.dp
+    pp = args.pp or max(len(jax.devices()) // dp, 1)
+    b = args.global_batch // args.microbatches
+    if isinstance(cfg, SkipViTConfig):
+        graph, fns = skipvit_pipeline_graph(cfg, batch=b), \
+            skipvit_model_fns(cfg)
     else:
-        cfg = UViTConfig("uvit-pp", img_size=8, in_ch=4, patch=2,
-                         d_model=64, n_layers=8, n_heads=4, d_ff=128,
-                         n_classes=10)
-        graph = uvit_pipeline_graph(cfg, batch=args.global_batch // M)
-        fns = diffusion_model_fns(cfg, "uvit")
-    compiled = auto_pipeline(graph, fns, dp * P, pipeline_devices=P,
-                             microbatches=M, dp_size=dp,
+        graph, fns = uvit_pipeline_graph(cfg, batch=b), \
+            diffusion_model_fns(cfg, "uvit")
+    compiled = auto_pipeline(graph, fns, dp * pp, pipeline_devices=pp,
+                             microbatches=args.microbatches, dp_size=dp,
                              zero_stage=args.zero_stage,
                              interleave=args.interleave,
                              wire_dtype=args.wire_dtype)
-    print("[train] " + compiled.describe().replace("\n", "\n[train] "))
-    params = compiled.init_pipeline_params(key)
-    opt_state = adamw_init(params)
+    return cfg, compiled
+
+
+def pipeline_step(compiled, cfg, mesh, opt_cfg):
+    """``(step, shardings)``: the jitted train step of the pipeline path
+    on ``mesh`` — pipelined loss and grads, the non-finite guard, AdamW —
+    and the shardings of its ``(params, opt_state, batch, rng, lr)``.
+
+    Stage stacks sit over ``"model"`` (ZeRO-2: one block dim over
+    ``"data"`` too), edge params and inputs are replicated, and the step
+    donates params and optimizer state, so the devices hold one copy of
+    the training state.
+    """
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.optim import adamw_update
+    from repro.runtime.adapters import make_diffusion_microbatches
+    from repro.runtime.resilience import all_finite
+
+    M = compiled.pcfg.num_microbatches
     loss_of_mb = compiled.bind(mesh)
-
-    ds = SyntheticLatentDataset(img_size=8, channels=4, n_classes=10)
-    loader = ShardedLoader(ds, global_batch=args.global_batch)
-
-    def pack(raw):
-        return {k: jnp.asarray(v) for k, v in raw.items()}
 
     def loss_of(params, batch, rng):
         mb, aux = make_diffusion_microbatches(batch, rng, M, cfg, "uvit")
         return loss_of_mb(params, mb, aux)
 
-    @jax.jit
-    def step_fn(params, opt_state, batch, rng, lr):
+    def step(params, opt_state, batch, rng, lr):
         loss, grads = jax.value_and_grad(loss_of)(params, batch, rng)
         finite = all_finite(loss, grads)
         gnorm = _grad_norm(grads)
-        new_p, new_o = adamw_update(params, grads, opt_state, opt_cfg,
-                                    lr=lr)
+        # the update runs inside the taken branch, so with the state
+        # donated the old and the new state are never live side by side
         params, opt_state = jax.lax.cond(
-            finite, lambda: (new_p, new_o), lambda: (params, opt_state))
+            finite,
+            lambda: adamw_update(params, grads, opt_state, opt_cfg, lr=lr),
+            lambda: (params, opt_state))
         return params, opt_state, loss, finite, gnorm
 
-    return params, opt_state, step_fn, loader, pack, compiled
+    rep = NamedSharding(mesh, P())
+    p_shard = compiled.param_shardings(mesh)
+    o_shard = {"m": p_shard, "v": p_shard, "step": rep}
+    shardings = (p_shard, o_shard, rep, rep, rep)
+    return jax.jit(step, donate_argnums=(0, 1),
+                   out_shardings=shardings), shardings
+
+
+def _build_pipeline_trainer(args, key, opt_cfg):
+    """Trainer on the PULSE compile path: graph -> partition -> schedule
+    -> table executor (runtime.compile) -> AdamW, on a (dp, pp) mesh of
+    the devices present, with params and optimizer state created in
+    place where the step reads them."""
+    import jax
+
+    from repro.optim import adamw_init
+
+    cfg, compiled = pipeline_plan(args)
+    print("[train] " + compiled.describe().replace("\n", "\n[train] "))
+    mesh = _pipeline_mesh(compiled.pcfg.dp_size, compiled.pcfg.num_devices)
+    step, shardings = pipeline_step(compiled, cfg, mesh, opt_cfg)
+    p_shard, o_shard, rep = shardings[:3]
+    params = jax.jit(compiled.init_pipeline_params,
+                     out_shardings=p_shard)(key)
+    opt_state = jax.jit(adamw_init, out_shardings=o_shard)(params)
+    loader = pipeline_loader(cfg, args.global_batch)
+
+    def pack(raw):
+        return jax.device_put(raw, rep)
+
+    return (params, opt_state, _CompiledStep(step, shardings), loader,
+            pack, compiled)
 
 
 if __name__ == "__main__":

@@ -131,16 +131,21 @@ def _skip_project(p: Params, x: Array, skip: Array, cfg) -> Array:
     """Decoder skip-in projection: ``y = [x | skip] @ skip_proj``.
 
     With ``cfg.use_skip_kernel`` the fused Pallas kernel
-    (``h @ W1 + s @ W2``, f32 accumulation; interpret mode off-TPU)
+    (``h @ W1 + s @ W2``, f32 accumulation; interpret mode on the CPU)
     replaces the concat matmul — the concat materialises the ``(.., 2D)``
-    activation in HBM just to read it back once.  Falls back to the
-    reference contraction when the operand shapes do not tile the
-    kernel's 128-square MXU blocks.
+    activation in HBM just to read it back once.  Operand shapes that do
+    not tile the kernel's 128-square MXU blocks raise: a caller that asks
+    for the kernel gets it, never a silent switch to the reference.
     """
     w = p["skip_proj"].astype(x.dtype)
-    if getattr(cfg, "use_skip_kernel", False) and \
-            skip_concat_matmul_supported(math.prod(x.shape[:-1]),
-                                         x.shape[-1], w.shape[1]):
+    if getattr(cfg, "use_skip_kernel", False):
+        rows = math.prod(x.shape[:-1])
+        if not skip_concat_matmul_supported(rows, x.shape[-1], w.shape[1]):
+            raise ValueError(
+                f"use_skip_kernel: skip-in operands ({rows}, {x.shape[-1]}) "
+                f"x ({w.shape[0]}, {w.shape[1]}) do not tile the fused "
+                "kernel's blocks (rows = tokens x batch must be a multiple "
+                "of 128 or at most 128)")
         return skip_concat_matmul(x, skip.astype(x.dtype), w)
     return jnp.concatenate([x, skip], axis=-1) @ w
 

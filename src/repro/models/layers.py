@@ -69,8 +69,6 @@ def apply_rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
 
 # --------------------------------------------------------------------------
 # Attention (GQA / MQA / MHA, causal + sliding window), dense reference.
-# The Pallas flash kernel (kernels/flash_attention.py) is a drop-in
-# replacement selected by config `use_flash`.
 # --------------------------------------------------------------------------
 
 def _attn_mask(q_len: int, kv_len: int, *, causal: bool, window: int | None,

@@ -72,7 +72,7 @@ class LMPipelineAdapter:
         return (_regroup(half, D), _regroup(rest, D, reverse=True)), edge
 
     def merge_params(self, stacks: tuple, edge: Pytree) -> Pytree:
-        stacks = tree_to_host(stacks)   # legacy-JAX shard reassembly fix
+        stacks = tree_to_host(stacks)   # sharded stacks: see tree_to_host
         if not self.wave:
             layers = _ungroup(stacks[0])
         else:
@@ -162,7 +162,7 @@ class DiffusionPipelineAdapter:
         return (enc, dec), edge
 
     def merge_params(self, stacks: tuple, edge: Pytree) -> Pytree:
-        stacks = tree_to_host(stacks)   # legacy-JAX shard reassembly fix
+        stacks = tree_to_host(stacks)   # sharded stacks: see tree_to_host
         return {**edge,
                 "enc_blocks": _ungroup(stacks[0]),
                 "dec_blocks": _ungroup(stacks[1], reverse=True)}

@@ -1,55 +1,33 @@
-"""Version-compatible JAX API imports.
+"""JAX APIs whose home or behaviour moves between releases, in one place.
 
-``jax.shard_map`` is a top-level API from JAX 0.6 on; earlier releases ship
-it as ``jax.experimental.shard_map.shard_map`` with a ``check_rep`` kwarg
-instead of ``check_vma``.  Every caller in this repo (runtime, train steps,
-tests/helpers, benchmarks) imports ``shard_map`` from here and writes
-against the modern signature; this wrapper translates for old releases.
-
-Policy (see README "JAX compat imports"): never ``from jax import <new
-API>`` directly in runtime or test code — route through this module so a
-single site handles the version split.
+Runtime, train-step, test-helper and benchmark code imports ``shard_map``
+from here (README "JAX compat imports"); the lint rule
+``compat-only-experimental`` keeps ``jax.experimental`` imports out of
+everything but this module, ``runtime/sharding.py`` and the kernels.
 """
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any
 
-try:  # JAX >= 0.6: public API, `check_vma` kwarg
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-    _LEGACY = False
-except ImportError:  # JAX < 0.6: experimental API, `check_rep` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _LEGACY = True
-
-
-def shard_map(f: Callable, mesh: Any = None, in_specs: Any = None,
-              out_specs: Any = None, check_vma: bool = True,
-              **kwargs) -> Callable:
-    """``jax.shard_map`` with the modern signature on any JAX version."""
-    if _LEGACY:
-        kwargs.setdefault("check_rep", check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma, **kwargs)
+from jax import shard_map  # noqa: F401  (re-exported)
 
 
 def tree_to_host(tree: Any) -> Any:
     """Pull every concrete array in a pytree to host memory.
 
-    Workaround for a legacy-JAX CPU miscompile: re-assembling shard_map
-    gradient outputs (NamedSharding over the 'model' axis, replicated over
-    'data') with ``jnp.concatenate`` outside jit inserts an all-reduce that
-    treats the replicated 'data' copies as partial sums — every value comes
-    back exactly dp_size times too large.  Device_get first: the host copy
-    is a plain committed array and reassembles correctly.  No-op on tracers
-    so merge helpers stay usable under jit (where sharding propagation
-    handles the concat correctly).
-
-    Applied on every JAX version, not just the legacy branch: the host
-    copy costs one transfer per merge (a cold path — grad checks and
-    checkpoint export), while gating on the version risks silent wrong
-    gradients on untested intermediate releases.  Correctness wins.
+    Stage stacks that come out of a ``bind``-ed executor (parameters or
+    gradients) are sharded over the ``"model"`` axis and, under ZeRO-2,
+    over ``"data"`` too.  On JAX 0.9 the ``jax.make_mesh`` default gives
+    those meshes explicit axis types, and re-assembling the stacks on the
+    device fails outright (the ``linear-zero2`` / ``wave-zero1`` /
+    ``wave-zero2`` equivalence configs without this pull): eagerly with a
+    ``ShardingTypeError`` on the slot gather of a ``[2@model, ...,
+    32@data, ...]`` ZeRO-2 stack, and under jit with "slicing on sharded
+    dims ... not divisible by mesh axes" on the per-device row slice.
+    The host copy is a plain array that slices and concatenates without a
+    sharding.  No-op on tracers, so merge helpers stay usable under jit.
+    Merging is a cold path (gradient checks, checkpoint export, a caller
+    asking for the logical params), so one transfer per merge is cheap.
     """
     import jax
     import numpy as np

@@ -37,7 +37,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.graph import BlockGraph
 from repro.core.hw import Hardware, TPU_V5E
@@ -282,7 +282,7 @@ class StageLayout:
 
     def _unstack(self, stacked: Pytree,
                  ranges: Sequence[Sequence[tuple[int, int]]]) -> Pytree:
-        stacked = tree_to_host(stacked)   # legacy-JAX shard reassembly fix
+        stacked = tree_to_host(stacked)   # sharded stacks: see tree_to_host
         order = sorted(
             ((d, v) for d in range(len(ranges))
              for v in range(len(ranges[d]))),
@@ -442,6 +442,24 @@ class CompiledPipeline:
             specs.append(sp)
             dims.append(dm)
         return tuple(specs), tuple(dims)
+
+    def param_shardings(self, mesh) -> tuple:
+        """``NamedSharding`` pytree of ``(stage_stacks, edge)`` on
+        ``mesh``: the layout the executor :meth:`bind` returns reads —
+        each stack over the pipeline axis (ZeRO-2: one block dim over the
+        data axes too), edge params replicated.  State placed with these
+        is consumed without a reshard."""
+        stacks, edge = jax.eval_shape(self.init_pipeline_params,
+                                      jax.random.PRNGKey(0))
+        specs, _ = self._zero_layout()
+        if specs is None:
+            specs = tuple(jax.tree.map(lambda _: P(self.pcfg.axis), st)
+                          for st in stacks)
+        named = lambda spec: NamedSharding(mesh, spec)
+        return (tuple(jax.tree.map(named, sp,
+                                   is_leaf=lambda x: isinstance(x, P))
+                      for sp in specs),
+                jax.tree.map(lambda _: named(P()), edge))
 
     # ---- executor ------------------------------------------------------
     def build(self) -> Callable:

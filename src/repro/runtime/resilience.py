@@ -654,6 +654,8 @@ class Heartbeat:
     step_s: float | None = None     # worker-measured duration of `step`
     pid: int | None = None
     gen: int = 0
+    start: int | None = None        # train beats: the first step this
+    #                                 worker ran (the one paying warm-up)
 
 
 def _heartbeat_path(directory: str, host_id: int) -> str:
@@ -741,7 +743,11 @@ class Watchdog:
             if h not in self._last:
                 continue
             if hb.phase == "train":
-                self._first_train.setdefault(h, hb.step)
+                # a warm worker can pass its first steps between two
+                # polls: its own start step, not the first one seen,
+                # is the step that paid the warm-up
+                self._first_train.setdefault(
+                    h, hb.step if hb.start is None else hb.start)
             phase, step, _ = self._last[h]
             if (hb.phase, hb.step) != (phase, step):
                 self._last[h] = (hb.phase, hb.step, now)

@@ -715,8 +715,6 @@ def make_wave_pipeline_from_schedule(
         def dec_stage_fn(stage_p, x, skips, aux_m, slot):  # noqa: F811
             stage_p = zero_all_gather(stage_p, dec_dims, cfg.data_axes)
             return dec_inner(stage_p, x, skips, aux_m, slot)
-    enc_stage = _wrap_remat(enc_stage_fn, cfg)
-    dec_stage = _wrap_remat(dec_stage_fn, cfg)
 
     def fn(enc_stack, dec_stack, edge_p, mbs, aux):
         d = jax.lax.axis_index(axis)
@@ -729,7 +727,7 @@ def make_wave_pipeline_from_schedule(
         zero_x = jnp.zeros(x_proto.shape, x_proto.dtype)
         zero_w = jnp.zeros(x_proto.shape, wire)
         skips_proto = jax.eval_shape(
-            lambda p, x, a: enc_stage(p, x, a, 0)[1],
+            lambda p, x, a: enc_stage_fn(p, x, a, 0)[1],
             tree_index(enc_p, 0), zero_x, aux0)
         zero_skips = jax.tree.map(
             lambda t: jnp.zeros(t.shape, t.dtype), skips_proto)
@@ -771,41 +769,50 @@ def make_wave_pipeline_from_schedule(
                   if up_used else up_pl)
             return down, up
 
-        def body(down_in, up_in, enc_rx, dec_rx, turn, cache, t):
-            enc_rx = _buf_store(enc_rx, dsl_t[t], down_in, dok_t[t])
-            dec_rx = _buf_store(dec_rx, usl_t[t], up_in, uok_t[t])
-            sel = sel_t[t]
-            vslot = slot_t[t]
-            m = mb_t[t]
-            mb_m = tree_index(mbs, m)
-            aux_m = tree_index(aux, m)
-
+        def compute(enc_p, dec_p, edge_p, sel, vslot, emb, x_rx_enc,
+                    x_in_dec, skips_m, mb_m, aux_m):
             def run_idle(_):
                 return zero_x, zero_skips
 
             def run_enc(_):
                 x0 = jax.lax.cond(
-                    emb_t[t], lambda: embed_fn(edge_p, mb_m, aux_m),
+                    emb, lambda: embed_fn(edge_p, mb_m, aux_m),
                     lambda: zero_x)
-                x_rx = tree_index(enc_rx, rx_t[t]).astype(zero_x.dtype)
-                x_in = jnp.where(emb_t[t], x0, x_rx)
-                return enc_stage(tree_index(enc_p, vslot), x_in, aux_m,
-                                 vslot)
+                x_in = jnp.where(emb, x0, x_rx_enc)
+                return enc_stage_fn(tree_index(enc_p, vslot), x_in, aux_m,
+                                    vslot)
 
             def run_dec(_):
-                x_rx = tree_index(dec_rx, rx_t[t]).astype(zero_x.dtype)
-                x_in = jnp.where(trd_t[t], tree_index(turn, trds_t[t]),
-                                 x_rx)
-                # gather the stash slots holding this microbatch's V
-                # encoder-slot entries -> the flat [V * enc_pad] view
-                # consumers address via StageLayout.skip_rows
-                skips_m = _gather_rows(cache, srd_t[t])
-                x_out = dec_stage(tree_index(dec_p, vslot), x_in, skips_m,
-                                  aux_m, vslot)
+                x_out = dec_stage_fn(tree_index(dec_p, vslot), x_in_dec,
+                                     skips_m, aux_m, vslot)
                 return x_out, zero_skips
 
-            x_out, skips = jax.lax.switch(
-                sel, (run_idle, run_enc, run_dec), None)
+            return jax.lax.switch(sel, (run_idle, run_enc, run_dec), None)
+
+        # One remat region per step, around the stage switch: its saved
+        # inputs are the loop-invariant stacks (forwarded, not stacked
+        # over the T scan steps) and this step's activations.  A region
+        # inside the switch would save the stacks as per-step switch
+        # outputs, T copies of every parameter.
+        compute = _wrap_remat(compute, cfg)
+
+        def body(down_in, up_in, enc_rx, dec_rx, turn, cache, t):
+            enc_rx = _buf_store(enc_rx, dsl_t[t], down_in, dok_t[t])
+            dec_rx = _buf_store(dec_rx, usl_t[t], up_in, uok_t[t])
+            m = mb_t[t]
+            mb_m = tree_index(mbs, m)
+            aux_m = tree_index(aux, m)
+            x_rx_enc = tree_index(enc_rx, rx_t[t]).astype(zero_x.dtype)
+            x_in_dec = jnp.where(
+                trd_t[t], tree_index(turn, trds_t[t]),
+                tree_index(dec_rx, rx_t[t]).astype(zero_x.dtype))
+            # gather the stash slots holding this microbatch's V
+            # encoder-slot entries -> the flat [V * enc_pad] view
+            # consumers address via StageLayout.skip_rows
+            skips_m = _gather_rows(cache, srd_t[t])
+            x_out, skips = compute(enc_p, dec_p, edge_p, sel_t[t],
+                                   slot_t[t], emb_t[t], x_rx_enc, x_in_dec,
+                                   skips_m, mb_m, aux_m)
             # gated stores: only the turnaround slot's output is read back
             # from the turn buffer, and only stash entries some decoder
             # row consumes are written (dead stores are elided — the
@@ -899,7 +906,6 @@ def make_linear_pipeline_from_schedule(
         def stage_fn(stage_p, x, slot):  # noqa: F811
             stage_p = zero_all_gather(stage_p, zero_dims, cfg.data_axes)
             return stage_inner(stage_p, x, slot)
-    stage = _wrap_remat(stage_fn, cfg)
 
     def fn(stack, edge_p, mbs):
         d = jax.lax.axis_index(axis)
@@ -925,24 +931,28 @@ def make_linear_pipeline_from_schedule(
             return (jax.lax.ppermute(h_pl, axis, down_perm)
                     if down_used else h_pl)
 
-        def body(h_in, rx, t):
-            rx = _buf_store(rx, dsl_t[t], h_in, dok_t[t])
-            m = mb_t[t]
-            vslot = slot_t[t]
-            mb_m = tree_index(mbs, m)
-
+        def compute(my_p, edge_p, sel, vslot, emb, x_rx, mb_m):
             def run_idle(_):
                 return zero_x
 
             def run_stage(_):
                 x0 = jax.lax.cond(
-                    emb_t[t], lambda: embed_fn(edge_p, mb_m),
-                    lambda: zero_x)
-                x_rx = tree_index(rx, rx_t[t]).astype(zero_x.dtype)
-                x_in = jnp.where(emb_t[t], x0, x_rx)
-                return stage(tree_index(my_p, vslot), x_in, vslot)
+                    emb, lambda: embed_fn(edge_p, mb_m), lambda: zero_x)
+                x_in = jnp.where(emb, x0, x_rx)
+                return stage_fn(tree_index(my_p, vslot), x_in, vslot)
 
-            x_out = jax.lax.switch(sel_t[t], (run_idle, run_stage), None)
+            return jax.lax.switch(sel, (run_idle, run_stage), None)
+
+        # one remat region per step, around the switch (see the wave
+        # executor: the stack stays a forwarded loop invariant)
+        compute = _wrap_remat(compute, cfg)
+
+        def body(h_in, rx, t):
+            rx = _buf_store(rx, dsl_t[t], h_in, dok_t[t])
+            mb_m = tree_index(mbs, mb_t[t])
+            x_rx = tree_index(rx, rx_t[t]).astype(zero_x.dtype)
+            x_out = compute(my_p, edge_p, sel_t[t], slot_t[t], emb_t[t],
+                            x_rx, mb_m)
             loss = jax.lax.cond(
                 loss_t[t],
                 lambda: loss_fn(edge_p, x_out, mb_m),

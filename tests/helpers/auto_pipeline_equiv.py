@@ -361,10 +361,7 @@ def _run_hunyuan(name, *, pipeline_devices=2, microbatches=4):
     the aux conditioning was produced from the same params).  Gradients are
     checked against a block-loop reference that, like the executor, takes
     (temb, ctx) as microbatch data — both sides differentiate the same
-    function of the block/edge parameters.  Stage stacks are computed
-    outside the executor jit (see README "JAX compat imports": fusing
-    split_params into the same jit as the shard_map executor miscompiles
-    on legacy JAX)."""
+    function of the block/edge parameters."""
     from repro.models import diffusion as dm
     from repro.models.layers import rms_norm
 

@@ -27,7 +27,7 @@ import numpy as np  # noqa: E402
 BASE = ["--pipeline", "--devices", "8", "--dp", "2",
         "--microbatches", "2", "--global-batch", "4", "--steps", "6",
         "--ckpt-every", "2", "--log-every", "2", "--lr", "1e-3",
-        "--wire-dtype", "float32"]
+        "--wire-dtype", "float32", "--logical-params"]
 
 
 def _run(extra):

@@ -17,8 +17,9 @@ Both scenarios must finish with the uninterrupted reference loss
 trajectory (single process, same plan, no faults) at rtol 1e-4, with
 the full detect/rollback/shrink/restart event sequence in events.jsonl.
 
-Scenarios share one jit compilation cache (reference plan == generation
-0's plan, so workers mostly reuse the reference run's compilations).
+Scenarios share the checkout's persistent compilation cache (see
+``repro.launch.compile_cache``; reference plan == generation 0's plan, so
+workers mostly reuse the reference run's compilations).
 
 Usage: python tests/helpers/supervisor_drill.py [hostdown hang ...]
 Prints ``SUPERVISOR DRILL: ALL OK`` when every scenario passes.
@@ -29,9 +30,6 @@ import tempfile
 
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=4")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      tempfile.mkdtemp(prefix="repro_sup_cache_"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 STEPS = 12
 PLAN = ["--arch", "uvit-nano", "--pipeline", "--devices", "4",

@@ -98,6 +98,16 @@ def test_watchdog_first_train_step_is_lenient():
     assert dog.check(now=107.0)[0] == "suspect"  # now on the tight clock
 
 
+def test_watchdog_warm_worker_first_seen_past_its_start():
+    """A warm worker (compile-cache hit) can finish its first steps
+    between two polls: the lenient step is the worker's own start step,
+    not the first train beat the monitor happens to see."""
+    dog = Watchdog([0], stall_timeout=5, startup_timeout=100,
+                   miss_budget=2, now=0.0)
+    dog.observe({0: Heartbeat(0, 5, "train", start=0)}, now=0.0)
+    assert dog.check(now=11.0)[0] == "hung"      # stall clock, not startup
+
+
 def test_watchdog_done_and_ckpt_phases():
     dog = Watchdog([0], stall_timeout=5, miss_budget=2, now=0.0)
     dog.observe(_hb(0, 3, "ckpt"), now=0.0)
