@@ -1,17 +1,16 @@
 """Per-block cost profiling (paper §IV-A "profile layer runtimes").
 
-Two paths:
-
-- ``analytic_block_costs``: FLOPs / peak + bytes / HBM-bandwidth roofline
-  estimate — deterministic, used for dry-runs and the tuner on CPU where
-  wall-clock timing of TPU kernels is meaningless.
-- ``measure_block_times``: real wall-clock timing of jitted per-block apply
-  functions (usable on any backend; used by tests and the CPU examples).
+``analytic_block_costs``: FLOPs / peak + bytes / HBM-bandwidth roofline
+estimate — deterministic, used for dry-runs and the tuner on CPU where
+wall-clock timing of TPU kernels is meaningless.  Measured block costs
+come from a profiler trace of the training step instead: the program
+names its stages and the model's parts with ``jax.named_scope``
+(``runtime/scopes.py``) and ``bench/scopes.py`` sums each scope's device
+time.
 """
 from __future__ import annotations
 
-import time
-from typing import Callable, Sequence
+from typing import Sequence
 
 from repro.core.graph import Block, BlockGraph
 from repro.core.hw import Hardware, TPU_V5E
@@ -32,28 +31,6 @@ def analytic_block_costs(
         t = analytic_time(b.flops, bytes_moved, hw)
         out.append(Block(b.name, t, b.param_bytes, b.act_bytes, b.skip_bytes, b.flops))
     return tuple(out)
-
-
-def measure_block_times(
-    fns: Sequence[Callable],
-    args: Sequence[tuple],
-    *,
-    warmup: int = 1,
-    iters: int = 3,
-) -> list[float]:
-    """Wall-clock seconds per call for each jitted block function."""
-    import jax                       # lazy: core/ imports without jax
-
-    times = []
-    for fn, a in zip(fns, args):
-        jfn = jax.jit(fn)
-        for _ in range(warmup):
-            jax.block_until_ready(jfn(*a))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            jax.block_until_ready(jfn(*a))
-        times.append((time.perf_counter() - t0) / iters)
-    return times
 
 
 def reprofile_graph(graph: BlockGraph, hw: Hardware = TPU_V5E) -> BlockGraph:

@@ -289,29 +289,32 @@ def run(args) -> TrainResult:
 
     import time
 
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
     from repro.checkpoint import CheckpointError, wait_step_complete
 
     def save_at(step_next):
         """Single-host: async save.  Multi-host: blocking shard write +
         rendezvous on step completeness (the commit barrier)."""
-        state = {"params": params, "opt": opt_state}
-        # a checkpoint save IS progress — tell the watchdog so a slow
-        # commit (device_get + hashing on a busy box) is not mistaken
-        # for a stalled step loop
-        beat(step_next, "ckpt")
-        if not multi_host:
-            mgr.save_async(step_next, state)
-            return
-        if mgr.save(step_next, state) is None:
-            return                  # degraded save: no barrier to meet
-        try:
-            wait_step_complete(args.ckpt_dir, step_next,
-                               timeout=args.commit_timeout)
-        except CheckpointError as e:
-            # degrade-and-warn, same contract as single-host iofail: the
-            # supervisor's watchdog owns declaring a peer dead
-            print(f"[train] WARNING: commit barrier at step {step_next} "
-                  f"did not close: {e}")
+        with TraceAnnotation("ckpt_save"):
+            state = {"params": params, "opt": opt_state}
+            # a checkpoint save IS progress — tell the watchdog so a slow
+            # commit (device_get + hashing on a busy box) is not mistaken
+            # for a stalled step loop
+            beat(step_next, "ckpt")
+            if not multi_host:
+                mgr.save_async(step_next, state)
+                return
+            if mgr.save(step_next, state) is None:
+                return              # degraded save: no barrier to meet
+            try:
+                wait_step_complete(args.ckpt_dir, step_next,
+                                   timeout=args.commit_timeout)
+            except CheckpointError as e:
+                # degrade-and-warn, same contract as single-host iofail:
+                # the supervisor's watchdog owns declaring a peer dead
+                print(f"[train] WARNING: commit barrier at step "
+                      f"{step_next} did not close: {e}")
 
     t0 = time.time()
     loss = None
@@ -320,55 +323,66 @@ def run(args) -> TrainResult:
     # what a straggler's peers actually experience (the device-blocking
     # slice alone can be a small fraction of the wall period)
     t_step = time.time()
+    # Host spans on the profiler's clock, under the benchmark harness's
+    # names (batch, place, dispatch, loss_read), inside one ``train`` step
+    # annotation a step; ``ckpt_save`` spans a checkpoint save (``save_at``).
     for step in range(start, args.steps):
-        if faults.hang_before(step):
-            # unreachable in practice (hang sleeps ~forever and the
-            # supervisor kills us) — guard for mocked sleeps in tests
-            print(f"[train] fault plan: woke from hang at step {step}")
-            t_step = time.time()
-        batch = faults.poison_batch(pack(loader.get(step)), step)
-        rng = jax.random.fold_in(key, step)
-        lr = cosine_schedule(step, base_lr=args.lr, warmup=20,
-                             total=args.steps)
-        params, opt_state, loss, finite, gnorm = step_fn(
-            params, opt_state, batch, rng, lr)
-        try:
-            guard.observe(bool(finite), step)
-        except GradGuardEscalation as e:
-            if args.escalation == "rollback":
-                print(f"[train] {e}; requesting supervisor rollback")
-                if mgr:
-                    mgr.wait()
-                beat(step, "done")
-                raise SystemExit(EXIT_ESCALATE) from None
-            raise
-        losses[step] = float(loss)
-        step_times[step] = time.time() - t_step
-        if args.out_json:
-            # incremental (atomic) trajectory dump: a worker killed or
-            # torn down mid-run still leaves its losses for the
-            # supervisor to merge
-            _dump_losses(args.out_json, losses, start)
-        slow = faults.slow_factor(step)
-        if slow > 1.0:     # straggle: stretch this step by the factor
-            time.sleep(min((time.time() - t_step) * (slow - 1.0), 5.0))
-        # the measured duration rides the heartbeat: a supervisor starved
-        # of poll slots still gets exact per-step samples for straggler
-        # detection (time-derived deltas would average over jit warmup)
-        beat(step, "train", loss=float(loss), gnorm=float(gnorm),
-             step_s=time.time() - t_step)
-        if step % args.log_every == 0 or step == args.steps - 1:
-            sps = (step - start + 1) * args.global_batch / (time.time() - t0)
-            print(f"[train] step {step:5d} loss {float(loss):.4f} "
-                  f"lr {float(lr):.2e} ({sps:.1f} samples/s)")
-        if mgr and (step + 1) % args.ckpt_every == 0:
-            save_at(step + 1)
-        if faults.post_step(step + 1, ckpt_dir=args.ckpt_dir,
-                            flush=mgr.wait if mgr else None) == "stop":
-            print(f"[train] fault plan: abrupt stop after step {step} "
-                  "(no final save)")
-            return finish(loss)
-        t_step = time.time()   # boundary: commit barrier waits excluded
+        with StepTraceAnnotation("train", step_num=step):
+            if faults.hang_before(step):
+                # unreachable in practice (hang sleeps ~forever and the
+                # supervisor kills us) — guard for mocked sleeps in tests
+                print(f"[train] fault plan: woke from hang at step {step}")
+                t_step = time.time()
+            with TraceAnnotation("batch"):
+                raw = loader.get(step)
+            with TraceAnnotation("place"):
+                batch = faults.poison_batch(pack(raw), step)
+                rng = jax.random.fold_in(key, step)
+            lr = cosine_schedule(step, base_lr=args.lr, warmup=20,
+                                 total=args.steps)
+            with TraceAnnotation("dispatch"):
+                params, opt_state, loss, finite, gnorm = step_fn(
+                    params, opt_state, batch, rng, lr)
+            with TraceAnnotation("loss_read"):
+                finite = bool(finite)
+            try:
+                guard.observe(finite, step)
+            except GradGuardEscalation as e:
+                if args.escalation == "rollback":
+                    print(f"[train] {e}; requesting supervisor rollback")
+                    if mgr:
+                        mgr.wait()
+                    beat(step, "done")
+                    raise SystemExit(EXIT_ESCALATE) from None
+                raise
+            losses[step] = float(loss)
+            step_times[step] = time.time() - t_step
+            if args.out_json:
+                # incremental (atomic) trajectory dump: a worker killed or
+                # torn down mid-run still leaves its losses for the
+                # supervisor to merge
+                _dump_losses(args.out_json, losses, start)
+            slow = faults.slow_factor(step)
+            if slow > 1.0:     # straggle: stretch this step by the factor
+                time.sleep(min((time.time() - t_step) * (slow - 1.0), 5.0))
+            # the measured duration rides the heartbeat: a supervisor starved
+            # of poll slots still gets exact per-step samples for straggler
+            # detection (time-derived deltas would average over jit warmup)
+            beat(step, "train", loss=float(loss), gnorm=float(gnorm),
+                 step_s=time.time() - t_step)
+            if step % args.log_every == 0 or step == args.steps - 1:
+                sps = ((step - start + 1) * args.global_batch
+                       / (time.time() - t0))
+                print(f"[train] step {step:5d} loss {float(loss):.4f} "
+                      f"lr {float(lr):.2e} ({sps:.1f} samples/s)")
+            if mgr and (step + 1) % args.ckpt_every == 0:
+                save_at(step + 1)
+            if faults.post_step(step + 1, ckpt_dir=args.ckpt_dir,
+                                flush=mgr.wait if mgr else None) == "stop":
+                print(f"[train] fault plan: abrupt stop after step {step} "
+                      "(no final save)")
+                return finish(loss)
+            t_step = time.time()   # boundary: commit barrier waits excluded
     if mgr:
         save_at(args.steps)
         mgr.wait()
@@ -581,6 +595,7 @@ def pipeline_step(compiled, cfg, mesh, opt_cfg):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.optim import adamw_update
+    from repro.runtime import scopes
     from repro.runtime.adapters import make_diffusion_microbatches
     from repro.runtime.resilience import all_finite
 
@@ -593,14 +608,16 @@ def pipeline_step(compiled, cfg, mesh, opt_cfg):
 
     def step(params, opt_state, batch, rng, lr):
         loss, grads = jax.value_and_grad(loss_of)(params, batch, rng)
-        finite = all_finite(loss, grads)
-        gnorm = _grad_norm(grads)
-        # the update runs inside the taken branch, so with the state
-        # donated the old and the new state are never live side by side
-        params, opt_state = jax.lax.cond(
-            finite,
-            lambda: adamw_update(params, grads, opt_state, opt_cfg, lr=lr),
-            lambda: (params, opt_state))
+        with jax.named_scope(scopes.OPTIMIZER):
+            finite = all_finite(loss, grads)
+            gnorm = _grad_norm(grads)
+            # the update runs inside the taken branch, so with the state
+            # donated the old and the new state are never live side by side
+            params, opt_state = jax.lax.cond(
+                finite,
+                lambda: adamw_update(params, grads, opt_state, opt_cfg,
+                                     lr=lr),
+                lambda: (params, opt_state))
         return params, opt_state, loss, finite, gnorm
 
     rep = NamedSharding(mesh, P())

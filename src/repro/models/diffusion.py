@@ -29,6 +29,7 @@ from repro.kernels.skip_matmul import (skip_concat_matmul,
                                        skip_concat_matmul_supported)
 from repro.models import layers as L
 from repro.models.layers import AttnConfig, Params, Array
+from repro.runtime import scopes
 
 
 # --------------------------------------------------------------------------
@@ -127,6 +128,7 @@ def _init_vit_block(key, cfg, d_ff: int, with_skip: bool,
     return p
 
 
+@jax.named_scope(scopes.SKIP_PROJ)
 def _skip_project(p: Params, x: Array, skip: Array, cfg) -> Array:
     """Decoder skip-in projection: ``y = [x | skip] @ skip_proj``.
 

@@ -17,6 +17,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import scopes
+
 Params = dict
 Array = jax.Array
 
@@ -84,6 +86,7 @@ def _attn_mask(q_len: int, kv_len: int, *, causal: bool, window: int | None,
     return mask
 
 
+@jax.named_scope(scopes.ATTENTION)
 def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
               window: int | None = None, q_offset: Array | int = 0,
               kv_valid_len: Array | None = None) -> Array:
@@ -291,6 +294,7 @@ def init_gelu_mlp(key, d_model: int, d_ff: int, dtype=jnp.float32) -> Params:
     }
 
 
+@jax.named_scope(scopes.MLP)
 def apply_gelu_mlp(p: Params, x: Array) -> Array:
     return jax.nn.gelu(x @ p["w_up"] + p["b_up"]) @ p["w_down"] + p["b_down"]
 

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.runtime import scopes
 from repro.runtime.compat import shard_map
 
 Pytree = Any
@@ -105,6 +106,7 @@ def tree_local(tree: Pytree) -> Pytree:
     return jax.tree.map(lambda x: x[0], tree)
 
 
+@jax.named_scope(scopes.ZERO_GATHER)
 def zero_all_gather(tree: Pytree, gather_dims: Pytree,
                     data_axes: tuple[str, ...]) -> Pytree:
     """All-gather ZeRO-2 rest-sharded stage params on use.

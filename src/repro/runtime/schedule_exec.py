@@ -95,6 +95,7 @@ import numpy as np
 
 from repro.core.schedule import (Schedule, placement_bounds_error,
                                  slot_maps)
+from repro.runtime import scopes
 from repro.runtime.pipeline import (WIRE_DTYPES, PipelineConfig,
                                     _wrap_remat, ring_perms, tree_index,
                                     tree_local, zero_all_gather)
@@ -716,6 +717,7 @@ def make_wave_pipeline_from_schedule(
             stage_p = zero_all_gather(stage_p, dec_dims, cfg.data_axes)
             return dec_inner(stage_p, x, skips, aux_m, slot)
 
+    @jax.named_scope(scopes.EXECUTOR)
     def fn(enc_stack, dec_stack, edge_p, mbs, aux):
         d = jax.lax.axis_index(axis)
         enc_p = tree_local(enc_stack)       # [V, enc_pad, ...]
@@ -762,6 +764,7 @@ def make_wave_pipeline_from_schedule(
             _zeros_buffer(zero_skips, W_skip),    # cache[W_skip]: skips
         )
 
+        @jax.named_scope(scopes.HOP)
         def hop(down_pl, up_pl):
             down = (jax.lax.ppermute(down_pl, axis, down_perm)
                     if down_used else down_pl)
@@ -774,14 +777,18 @@ def make_wave_pipeline_from_schedule(
             def run_idle(_):
                 return zero_x, zero_skips
 
+            @jax.named_scope(scopes.EMBED)
+            def embed():
+                return embed_fn(edge_p, mb_m, aux_m)
+
+            @jax.named_scope(scopes.STAGE_ENC)
             def run_enc(_):
-                x0 = jax.lax.cond(
-                    emb, lambda: embed_fn(edge_p, mb_m, aux_m),
-                    lambda: zero_x)
+                x0 = jax.lax.cond(emb, embed, lambda: zero_x)
                 x_in = jnp.where(emb, x0, x_rx_enc)
                 return enc_stage_fn(tree_index(enc_p, vslot), x_in, aux_m,
                                     vslot)
 
+            @jax.named_scope(scopes.STAGE_DEC)
             def run_dec(_):
                 x_out = dec_stage_fn(tree_index(dec_p, vslot), x_in_dec,
                                      skips_m, aux_m, vslot)
@@ -796,20 +803,32 @@ def make_wave_pipeline_from_schedule(
         # outputs, T copies of every parameter.
         compute = _wrap_remat(compute, cfg)
 
+        @jax.named_scope(scopes.HEAD)
+        def head(x_out, mb_m, aux_m):
+            return loss_fn(edge_p, x_out, mb_m, aux_m)
+
+        # The scopes below keep the order in which the body traces its
+        # operations, so the named program is the unnamed one.
         def body(down_in, up_in, enc_rx, dec_rx, turn, cache, t):
-            enc_rx = _buf_store(enc_rx, dsl_t[t], down_in, dok_t[t])
-            dec_rx = _buf_store(dec_rx, usl_t[t], up_in, uok_t[t])
+            with jax.named_scope(scopes.RX_STORE):
+                enc_rx = _buf_store(enc_rx, dsl_t[t], down_in, dok_t[t])
+                dec_rx = _buf_store(dec_rx, usl_t[t], up_in, uok_t[t])
             m = mb_t[t]
             mb_m = tree_index(mbs, m)
             aux_m = tree_index(aux, m)
-            x_rx_enc = tree_index(enc_rx, rx_t[t]).astype(zero_x.dtype)
-            x_in_dec = jnp.where(
-                trd_t[t], tree_index(turn, trds_t[t]),
-                tree_index(dec_rx, rx_t[t]).astype(zero_x.dtype))
-            # gather the stash slots holding this microbatch's V
-            # encoder-slot entries -> the flat [V * enc_pad] view
-            # consumers address via StageLayout.skip_rows
-            skips_m = _gather_rows(cache, srd_t[t])
+            with jax.named_scope(scopes.RX_STORE):
+                x_rx_enc = tree_index(enc_rx, rx_t[t]).astype(zero_x.dtype)
+            turn_rd = trd_t[t]
+            with jax.named_scope(scopes.STASH):
+                x_turn = tree_index(turn, trds_t[t])
+            with jax.named_scope(scopes.RX_STORE):
+                x_rx_dec = tree_index(dec_rx, rx_t[t]).astype(zero_x.dtype)
+            x_in_dec = jnp.where(turn_rd, x_turn, x_rx_dec)
+            with jax.named_scope(scopes.STASH):
+                # gather the stash slots holding this microbatch's V
+                # encoder-slot entries -> the flat [V * enc_pad] view
+                # consumers address via StageLayout.skip_rows
+                skips_m = _gather_rows(cache, srd_t[t])
             x_out, skips = compute(enc_p, dec_p, edge_p, sel_t[t],
                                    slot_t[t], emb_t[t], x_rx_enc, x_in_dec,
                                    skips_m, mb_m, aux_m)
@@ -817,11 +836,12 @@ def make_wave_pipeline_from_schedule(
             # from the turn buffer, and only stash entries some decoder
             # row consumes are written (dead stores are elided — the
             # liveness analysis cleared their flags)
-            turn = _buf_store(turn, twrs_t[t], x_out, twr_t[t])
-            cache = _buf_store(cache, swrs_t[t], skips, swr_t[t])
+            with jax.named_scope(scopes.STASH):
+                turn = _buf_store(turn, twrs_t[t], x_out, twr_t[t])
+                cache = _buf_store(cache, swrs_t[t], skips, swr_t[t])
             loss = jax.lax.cond(
                 loss_t[t],
-                lambda: loss_fn(edge_p, x_out, mb_m, aux_m),
+                lambda: head(x_out, mb_m, aux_m),
                 lambda: jnp.zeros((), jnp.float32))
             # cast-on-send; quiescent hops carry zeros (the where
             # transpose zeroes their backward cotangents too)
@@ -857,7 +877,9 @@ def make_wave_pipeline_from_schedule(
 
         _, losses = jax.lax.scan(step, init, jnp.arange(T))
         total = jnp.sum(losses) / M
-        return jax.lax.psum(total, (axis, *cfg.data_axes)) / cfg.dp_size
+        with jax.named_scope(scopes.LOSS_ALLREDUCE):
+            total = jax.lax.psum(total, (axis, *cfg.data_axes))
+        return total / cfg.dp_size
 
     return fn
 
@@ -907,6 +929,7 @@ def make_linear_pipeline_from_schedule(
             stage_p = zero_all_gather(stage_p, zero_dims, cfg.data_axes)
             return stage_inner(stage_p, x, slot)
 
+    @jax.named_scope(scopes.EXECUTOR)
     def fn(stack, edge_p, mbs):
         d = jax.lax.axis_index(axis)
         my_p = tree_local(stack)            # [V, pad, ...]
@@ -927,6 +950,7 @@ def make_linear_pipeline_from_schedule(
 
         init = (zero_w, _zeros_buffer(zero_x, W_down, wire))
 
+        @jax.named_scope(scopes.HOP)
         def hop(h_pl):
             return (jax.lax.ppermute(h_pl, axis, down_perm)
                     if down_used else h_pl)
@@ -935,9 +959,13 @@ def make_linear_pipeline_from_schedule(
             def run_idle(_):
                 return zero_x
 
+            @jax.named_scope(scopes.EMBED)
+            def embed():
+                return embed_fn(edge_p, mb_m)
+
+            @jax.named_scope(scopes.STAGE_ENC)
             def run_stage(_):
-                x0 = jax.lax.cond(
-                    emb, lambda: embed_fn(edge_p, mb_m), lambda: zero_x)
+                x0 = jax.lax.cond(emb, embed, lambda: zero_x)
                 x_in = jnp.where(emb, x0, x_rx)
                 return stage_fn(tree_index(my_p, vslot), x_in, vslot)
 
@@ -947,15 +975,21 @@ def make_linear_pipeline_from_schedule(
         # executor: the stack stays a forwarded loop invariant)
         compute = _wrap_remat(compute, cfg)
 
+        @jax.named_scope(scopes.HEAD)
+        def head(x_out, mb_m):
+            return loss_fn(edge_p, x_out, mb_m)
+
         def body(h_in, rx, t):
-            rx = _buf_store(rx, dsl_t[t], h_in, dok_t[t])
+            with jax.named_scope(scopes.RX_STORE):
+                rx = _buf_store(rx, dsl_t[t], h_in, dok_t[t])
             mb_m = tree_index(mbs, mb_t[t])
-            x_rx = tree_index(rx, rx_t[t]).astype(zero_x.dtype)
+            with jax.named_scope(scopes.RX_STORE):
+                x_rx = tree_index(rx, rx_t[t]).astype(zero_x.dtype)
             x_out = compute(my_p, edge_p, sel_t[t], slot_t[t], emb_t[t],
                             x_rx, mb_m)
             loss = jax.lax.cond(
                 loss_t[t],
-                lambda: loss_fn(edge_p, x_out, mb_m),
+                lambda: head(x_out, mb_m),
                 lambda: jnp.zeros((), jnp.float32))
             h_pl = jnp.where(dsnd_t[t], x_out.astype(wire), zero_w)
             return h_pl, rx, loss
@@ -975,6 +1009,8 @@ def make_linear_pipeline_from_schedule(
 
         _, losses = jax.lax.scan(step, init, jnp.arange(T))
         total = jnp.sum(losses) / M
-        return jax.lax.psum(total, (axis, *cfg.data_axes)) / cfg.dp_size
+        with jax.named_scope(scopes.LOSS_ALLREDUCE):
+            total = jax.lax.psum(total, (axis, *cfg.data_axes))
+        return total / cfg.dp_size
 
     return fn
