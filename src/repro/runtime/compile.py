@@ -658,6 +658,11 @@ class CompiledPipeline:
             lines.append(
                 f"  comm: {mode}, exposed hops {tabs.exposed_hops} / "
                 f"hidden {tabs.hidden_hops} (of {live_d + live_u} live)")
+            lines.append(
+                "  weight rows: running steps per device "
+                f"{','.join(map(str, tabs.running_steps))} of "
+                f"{tabs.num_steps} (each adds its weight gradient into "
+                "one row)")
         if self.pcfg.dp_size > 1 or self.pcfg.zero_stage > 0:
             lines.append(
                 f"  hybrid: dp={self.pcfg.dp_size} over "

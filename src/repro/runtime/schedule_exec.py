@@ -259,6 +259,21 @@ class StepTables:
         return self.sel.shape[1]
 
     @property
+    def fetch_row(self) -> np.ndarray:
+        """``[D, num_steps]`` row of the ``[2V, pad, ...]`` gradient row
+        space (``_SlotRows``) each step fetches: ``slot`` on encoder (and
+        linear) steps, ``V + slot`` on decoder steps, 0 on idle steps
+        (whose weight cotangent is zero)."""
+        return np.where(self.sel == RUN_DEC, self.V + self.slot,
+                        self.slot).astype(np.int32)
+
+    @property
+    def running_steps(self) -> np.ndarray:
+        """``[D]`` steps on which each device runs a stage, each adding
+        its weight gradient into one row."""
+        return (self.sel != IDLE).sum(axis=1)
+
+    @property
     def live_hops(self) -> tuple[int, int]:
         """(down, up) hops that actually carry a message (fwd pass)."""
         return int(self.down_send.sum()), int(self.up_send.sum())
@@ -640,6 +655,156 @@ def _wire_dtype(cfg: PipelineConfig):
 
 
 # ===========================================================================
+# Per-step weight gradients, one row a step
+# ===========================================================================
+#
+# A scan step reads its stage's weights from the slot stacks inside the
+# stage switch, but takes their gradient through zero-valued row spaces
+# (``_SlotRows.sinks``) whose rows are operands of the switch.  The
+# transposed switch then returns one slot-sized row cotangent a step,
+# which XLA adds in place into the scan's gradient accumulator.  Had the
+# stacks themselves carried the gradient, they would be operands of the
+# switch: every branch would return a stack-sized cotangent for each
+# (zeros where it did not run), and the transposed scan would add them
+# all every step.  Leaves both kinds have share one ``[2V, pad, ...]``
+# row space, so a step adds into one row of it whichever kind it runs.
+
+
+@jax.custom_vjp
+def _tap(w, z):
+    """``w``, whose cotangent goes to ``z`` (a zero row of ``w``'s shape)
+    and not to ``w``."""
+    return w
+
+
+_tap.defvjp(lambda w, z: (w, None), lambda _, g: (None, g))
+
+
+def _index(leaves: list, i) -> list:
+    return [jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False)
+            for t in leaves]
+
+
+@dataclasses.dataclass(frozen=True)
+class _SlotRows:
+    """The gradient row spaces of the encoder and decoder slot stacks.
+
+    A leaf both kinds have at the same path, shape, dtype and ZeRO-2
+    gather dim has one ``[2V, pad, ...]`` row space: encoder slots at rows
+    ``0..V-1``, decoder slots at ``V..2V-1``, addressed by the step's
+    ``StepTables.fetch_row``.  Any other leaf (a decoder-only skip
+    projection, or stacks whose pads differ) has a ``[V, pad, ...]`` row
+    space of its own kind, addressed by the slot.
+    """
+
+    enc_def: Any
+    dec_def: Any
+    shared: tuple[tuple[int, int], ...]   # (enc leaf, dec leaf) pairs
+    enc_own: tuple[int, ...]
+    dec_own: tuple[int, ...]
+
+    @classmethod
+    def of(cls, enc_p: Pytree, dec_p: Pytree, dims=None) -> "_SlotRows":
+        enc_kv, enc_def = jax.tree_util.tree_flatten_with_path(enc_p)
+        dec_kv, dec_def = jax.tree_util.tree_flatten_with_path(dec_p)
+        enc_dims, dec_dims = ((jax.tree.leaves(dims[0]),
+                               jax.tree.leaves(dims[1]))
+                              if dims is not None else
+                              ([-1] * len(enc_kv), [-1] * len(dec_kv)))
+        dec_at = {path: j for j, (path, _) in enumerate(dec_kv)}
+
+        def key(leaf, dim):
+            return tuple(leaf.shape), jnp.dtype(leaf.dtype), dim
+
+        shared = []
+        for i, (path, leaf) in enumerate(enc_kv):
+            j = dec_at.get(path)
+            if j is not None and (key(leaf, enc_dims[i])
+                                  == key(dec_kv[j][1], dec_dims[j])):
+                shared.append((i, j))
+        enc_sh = {i for i, _ in shared}
+        dec_sh = {j for _, j in shared}
+        return cls(enc_def, dec_def, tuple(shared),
+                   tuple(i for i in range(len(enc_kv)) if i not in enc_sh),
+                   tuple(j for j in range(len(dec_kv)) if j not in dec_sh))
+
+    def sinks(self, enc_p: Pytree, dec_p: Pytree) -> tuple:
+        """Zero row spaces: (shared ``[2V, ...]``, encoder-own,
+        decoder-own) leaf lists."""
+        enc, dec = jax.tree.leaves(enc_p), jax.tree.leaves(dec_p)
+        zeros = lambda t, n: jnp.zeros((n * t.shape[0],) + t.shape[1:],
+                                       t.dtype)
+        return ([zeros(enc[i], 2) for i, _ in self.shared],
+                [zeros(enc[i], 1) for i in self.enc_own],
+                [zeros(dec[j], 1) for j in self.dec_own])
+
+    def rows(self, sinks: tuple, row, slot) -> tuple:
+        """A step's zero rows: the shared row spaces at ``row``, each
+        kind's own at ``slot``."""
+        shared, enc_own, dec_own = sinks
+        return _index(shared, row), _index(enc_own, slot), _index(dec_own,
+                                                                  slot)
+
+    def enc(self, enc_p: Pytree, z: tuple, slot) -> Pytree:
+        """The encoder slot's weights, their gradient into the rows ``z``
+        of :meth:`rows`."""
+        return self._slot(enc_p, z[0], z[1], [i for i, _ in self.shared],
+                          self.enc_own, slot)
+
+    def dec(self, dec_p: Pytree, z: tuple, slot) -> Pytree:
+        return self._slot(dec_p, z[0], z[2], [j for _, j in self.shared],
+                          self.dec_own, slot)
+
+    @staticmethod
+    def _slot(stack, shared, own, shared_at, own_at, slot) -> Pytree:
+        leaves, treedef = jax.tree.flatten(stack)
+        z = [None] * len(leaves)
+        for k, i in enumerate(shared_at):
+            z[i] = shared[k]
+        for k, i in enumerate(own_at):
+            z[i] = own[k]
+        return jax.tree.unflatten(treedef, [
+            _tap(w, zi) for w, zi in zip(_index(leaves, slot), z)])
+
+    def grads(self, g: tuple) -> tuple:
+        """The stacks' gradients from the row spaces' gradients ``g``."""
+        shared, enc_own, dec_own = g
+        enc = [None] * (len(self.shared) + len(self.enc_own))
+        dec = [None] * (len(self.shared) + len(self.dec_own))
+        for (i, j), gs in zip(self.shared, shared):
+            V = gs.shape[0] // 2
+            enc[i], dec[j] = gs[:V], gs[V:]
+        for i, gi in zip(self.enc_own, enc_own):
+            enc[i] = gi
+        for j, gj in zip(self.dec_own, dec_own):
+            dec[j] = gj
+        return (jax.tree.unflatten(self.enc_def, enc),
+                jax.tree.unflatten(self.dec_def, dec))
+
+
+def _grads_through_rows(run: Callable, rows: _SlotRows, enc_p, dec_p,
+                        *args):
+    """``run(enc_p, dec_p, sinks, *args)``, differentiated with respect to
+    the stacks through the row spaces ``sinks`` (the stacks are read as
+    constants); ``args`` are differentiated as usual."""
+    @jax.custom_vjp
+    def f(enc_p, dec_p, args):
+        return run(enc_p, dec_p, rows.sinks(enc_p, dec_p), *args)
+
+    def f_fwd(enc_p, dec_p, args):
+        out, vjp = jax.vjp(lambda z, a: run(enc_p, dec_p, z, *a),
+                           rows.sinks(enc_p, dec_p), args)
+        return out, vjp
+
+    def f_bwd(vjp, g):
+        g_sinks, g_args = vjp(g)
+        return (*rows.grads(g_sinks), g_args)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(enc_p, dec_p, args)
+
+
+# ===========================================================================
 # Folded wave executor from tables
 # ===========================================================================
 
@@ -672,7 +837,8 @@ def make_wave_pipeline_from_schedule(
     Each scan step consults the schedule-derived tables: arrivals are
     stored into rotating receive buffers sized by the proven windows, the
     selected stage slot runs on the slot's microbatch with its own
-    parameter rows (``stack[d, slot]``), encoder slots stash their skips
+    parameter rows (``stack[d, slot]``; their gradient comes back one row
+    a step, see ``_SlotRows``), encoder slots stash their skips
     under the precomputed stash slot — and the turnaround slot the
     activation under its turn slot — so each decoder slot reads exactly
     the skips its collocated encoder slot produced.  Boundary activations
@@ -719,9 +885,17 @@ def make_wave_pipeline_from_schedule(
 
     @jax.named_scope(scopes.EXECUTOR)
     def fn(enc_stack, dec_stack, edge_p, mbs, aux):
-        d = jax.lax.axis_index(axis)
         enc_p = tree_local(enc_stack)       # [V, enc_pad, ...]
         dec_p = tree_local(dec_stack)       # [V, dec_pad, ...]
+        rows = _SlotRows.of(enc_p, dec_p, zero_dims)
+        total = _grads_through_rows(functools.partial(run, rows), rows,
+                                    enc_p, dec_p, edge_p, mbs, aux)
+        with jax.named_scope(scopes.LOSS_ALLREDUCE):
+            total = jax.lax.psum(total, (axis, *cfg.data_axes))
+        return total / cfg.dp_size
+
+    def run(rows, enc_p, dec_p, sinks, edge_p, mbs, aux):
+        d = jax.lax.axis_index(axis)
 
         mb0 = tree_index(mbs, 0)
         aux0 = tree_index(aux, 0)
@@ -737,6 +911,7 @@ def make_wave_pipeline_from_schedule(
         # This device's rows of every table (host constants -> jnp).
         sel_t = jnp.asarray(tables.sel)[d]
         slot_t = jnp.asarray(tables.slot)[d]
+        row_t = jnp.asarray(tables.fetch_row)[d]
         mb_t = jnp.asarray(tables.mb)[d]
         dok_t = jnp.asarray(tables.down_valid)[d]
         uok_t = jnp.asarray(tables.up_valid)[d]
@@ -772,8 +947,8 @@ def make_wave_pipeline_from_schedule(
                   if up_used else up_pl)
             return down, up
 
-        def compute(enc_p, dec_p, edge_p, sel, vslot, emb, x_rx_enc,
-                    x_in_dec, skips_m, mb_m, aux_m):
+        def compute(enc_p, dec_p, sinks, edge_p, sel, row, vslot, emb,
+                    x_rx_enc, x_in_dec, skips_m, mb_m, aux_m):
             def run_idle(_):
                 return zero_x, zero_skips
 
@@ -782,19 +957,22 @@ def make_wave_pipeline_from_schedule(
                 return embed_fn(edge_p, mb_m, aux_m)
 
             @jax.named_scope(scopes.STAGE_ENC)
-            def run_enc(_):
+            def run_enc(z):
                 x0 = jax.lax.cond(emb, embed, lambda: zero_x)
                 x_in = jnp.where(emb, x0, x_rx_enc)
-                return enc_stage_fn(tree_index(enc_p, vslot), x_in, aux_m,
+                return enc_stage_fn(rows.enc(enc_p, z, vslot), x_in, aux_m,
                                     vslot)
 
             @jax.named_scope(scopes.STAGE_DEC)
-            def run_dec(_):
-                x_out = dec_stage_fn(tree_index(dec_p, vslot), x_in_dec,
+            def run_dec(z):
+                x_out = dec_stage_fn(rows.dec(dec_p, z, vslot), x_in_dec,
                                      skips_m, aux_m, vslot)
                 return x_out, zero_skips
 
-            return jax.lax.switch(sel, (run_idle, run_enc, run_dec), None)
+            # the step's gradient rows are the switch's operands (see
+            # _SlotRows): its transpose returns one row, not the stacks
+            return jax.lax.switch(sel, (run_idle, run_enc, run_dec),
+                                  rows.rows(sinks, row, vslot))
 
         # One remat region per step, around the stage switch: its saved
         # inputs are the loop-invariant stacks (forwarded, not stacked
@@ -829,9 +1007,9 @@ def make_wave_pipeline_from_schedule(
                 # encoder-slot entries -> the flat [V * enc_pad] view
                 # consumers address via StageLayout.skip_rows
                 skips_m = _gather_rows(cache, srd_t[t])
-            x_out, skips = compute(enc_p, dec_p, edge_p, sel_t[t],
-                                   slot_t[t], emb_t[t], x_rx_enc, x_in_dec,
-                                   skips_m, mb_m, aux_m)
+            x_out, skips = compute(enc_p, dec_p, sinks, edge_p, sel_t[t],
+                                   row_t[t], slot_t[t], emb_t[t], x_rx_enc,
+                                   x_in_dec, skips_m, mb_m, aux_m)
             # gated stores: only the turnaround slot's output is read back
             # from the turn buffer, and only stash entries some decoder
             # row consumes are written (dead stores are elided — the
@@ -876,10 +1054,7 @@ def make_wave_pipeline_from_schedule(
                 return (down_nx, up_nx, enc_rx, dec_rx, turn, cache), loss
 
         _, losses = jax.lax.scan(step, init, jnp.arange(T))
-        total = jnp.sum(losses) / M
-        with jax.named_scope(scopes.LOSS_ALLREDUCE):
-            total = jax.lax.psum(total, (axis, *cfg.data_axes))
-        return total / cfg.dp_size
+        return jnp.sum(losses) / M
 
     return fn
 
@@ -931,8 +1106,18 @@ def make_linear_pipeline_from_schedule(
 
     @jax.named_scope(scopes.EXECUTOR)
     def fn(stack, edge_p, mbs):
-        d = jax.lax.axis_index(axis)
         my_p = tree_local(stack)            # [V, pad, ...]
+        # one kind of stage: every leaf's row space is its own [V] stack
+        rows = _SlotRows.of(my_p, {}, None if zero_dims is None
+                            else (zero_dims, {}))
+        total = _grads_through_rows(functools.partial(run, rows), rows,
+                                    my_p, {}, edge_p, mbs)
+        with jax.named_scope(scopes.LOSS_ALLREDUCE):
+            total = jax.lax.psum(total, (axis, *cfg.data_axes))
+        return total / cfg.dp_size
+
+    def run(rows, my_p, _, sinks, edge_p, mbs):
+        d = jax.lax.axis_index(axis)
         mb0 = tree_index(mbs, 0)
         x_proto = jax.eval_shape(embed_fn, edge_p, mb0)
         zero_x = jnp.zeros(x_proto.shape, x_proto.dtype)
@@ -955,7 +1140,7 @@ def make_linear_pipeline_from_schedule(
             return (jax.lax.ppermute(h_pl, axis, down_perm)
                     if down_used else h_pl)
 
-        def compute(my_p, edge_p, sel, vslot, emb, x_rx, mb_m):
+        def compute(my_p, sinks, edge_p, sel, vslot, emb, x_rx, mb_m):
             def run_idle(_):
                 return zero_x
 
@@ -964,12 +1149,15 @@ def make_linear_pipeline_from_schedule(
                 return embed_fn(edge_p, mb_m)
 
             @jax.named_scope(scopes.STAGE_ENC)
-            def run_stage(_):
+            def run_stage(z):
                 x0 = jax.lax.cond(emb, embed, lambda: zero_x)
                 x_in = jnp.where(emb, x0, x_rx)
-                return stage_fn(tree_index(my_p, vslot), x_in, vslot)
+                return stage_fn(rows.enc(my_p, z, vslot), x_in, vslot)
 
-            return jax.lax.switch(sel, (run_idle, run_stage), None)
+            # the step's gradient rows are the switch's operands (see
+            # _SlotRows and the wave executor)
+            return jax.lax.switch(sel, (run_idle, run_stage),
+                                  rows.rows(sinks, vslot, vslot))
 
         # one remat region per step, around the switch (see the wave
         # executor: the stack stays a forwarded loop invariant)
@@ -985,8 +1173,8 @@ def make_linear_pipeline_from_schedule(
             mb_m = tree_index(mbs, mb_t[t])
             with jax.named_scope(scopes.RX_STORE):
                 x_rx = tree_index(rx, rx_t[t]).astype(zero_x.dtype)
-            x_out = compute(my_p, edge_p, sel_t[t], slot_t[t], emb_t[t],
-                            x_rx, mb_m)
+            x_out = compute(my_p, sinks, edge_p, sel_t[t], slot_t[t],
+                            emb_t[t], x_rx, mb_m)
             loss = jax.lax.cond(
                 loss_t[t],
                 lambda: head(x_out, mb_m),
@@ -1008,9 +1196,6 @@ def make_linear_pipeline_from_schedule(
                 return (hop(h_pl), rx), loss
 
         _, losses = jax.lax.scan(step, init, jnp.arange(T))
-        total = jnp.sum(losses) / M
-        with jax.named_scope(scopes.LOSS_ALLREDUCE):
-            total = jax.lax.psum(total, (axis, *cfg.data_axes))
-        return total / cfg.dp_size
+        return jnp.sum(losses) / M
 
     return fn
