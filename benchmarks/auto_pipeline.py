@@ -338,7 +338,7 @@ def run(json_sink: dict | None = None):
         ("skipvit26", skipvit_pipeline_graph(
             SkipViTConfig("b", n_enc=12, n_mid=2, n_dec=12),
             fwd_times=[_rnd.uniform(0.5, 3.0) for _ in range(26)]), 4),
-        ("hunyuan32", hunyuan_dit.pipeline_graph(), 4),
+        ("hunyuan40", hunyuan_dit.pipeline_graph(), 4),
     ]
     il_json: dict = {}
     for name, g, D in il_cases:
@@ -395,16 +395,17 @@ def run(json_sink: dict | None = None):
 
     # the hybrid tuner searches V as an axis (simulation scoring, default
     # TPU v5e memory budget): record the degree it picks for Hunyuan-DiT
+    # (DiT-g/2, 40 blocks at 1024 px)
     t0 = time.perf_counter()
     il_choices = tune(hunyuan_dit.pipeline_graph(), 4, hw=TPU_V5E,
                       use_simulation=True, interleave_options=(1, 2, 4))
     us = (time.perf_counter() - t0) * 1e6
     if il_choices:
         best = il_choices[0]
-        rows.append(f"auto_pipeline_interleave_tuner_hunyuan32_n4,{us:.0f},"
+        rows.append(f"auto_pipeline_interleave_tuner_hunyuan40_n4,{us:.0f},"
                     f"chose_P={best.P}_V={best.V}_b={best.b}"
                     f"_t_sample={best.t_sample:.3e}")
-        json_sink["tuner"] = {"graph": "hunyuan32", "N": 4, "P": best.P,
+        json_sink["tuner"] = {"graph": "hunyuan40", "N": 4, "P": best.P,
                               "V": best.V, "b": best.b,
                               "t_sample": best.t_sample}
 

@@ -22,9 +22,9 @@ MODELS = {
     "uvit": lambda: uvit_block_graph(
         UViTConfig("uvit", img_size=32, d_model=2560, n_layers=32,
                    n_heads=20, d_ff=10240), batch=32),
+    # DiT-g/2 at its published widths, 64x64 latents (512 px)
     "hunyuan": lambda: hunyuan_block_graph(
-        HunyuanDiTConfig("hy", img_size=64, d_model=2048, n_layers=32,
-                         n_heads=16, d_ff=8192), batch=32),
+        HunyuanDiTConfig("hy", img_size=64), batch=32),
 }
 
 

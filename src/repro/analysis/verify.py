@@ -5,7 +5,7 @@ and runs the full static dataflow proof on each lowering, printing one
 summary line per certificate and exiting non-zero if any plan fails:
 
     python -m repro.analysis.verify                    # all tier-1 configs
-    python -m repro.analysis.verify hunyuan32          # one config
+    python -m repro.analysis.verify hunyuan40          # one config
     python -m repro.analysis.verify --plan plan.json   # saved snapshot
     python -m repro.analysis.verify --use-ilp          # + ILP plans (slow)
 
@@ -29,7 +29,7 @@ import sys
 from repro.analysis.certificate import (PlanCertificate, certify_tables,
                                         export_plan, load_plan)
 
-TIER1_CONFIGS = ("sdv2unet29", "skipvit26", "hunyuan32")
+TIER1_CONFIGS = ("sdv2unet29", "skipvit26", "hunyuan40")
 INTERLEAVE_DEGREES = (1, 2, 4)
 
 
@@ -51,7 +51,7 @@ def tier1_graph(name: str):
         cfg = SkipViTConfig("b", n_enc=12, n_mid=2, n_dec=12)
         return skipvit_pipeline_graph(
             cfg, fwd_times=[rnd.uniform(0.5, 3.0) for _ in range(26)]), 4
-    if name == "hunyuan32":
+    if name == "hunyuan40":
         from repro.configs import hunyuan_dit
         return hunyuan_dit.pipeline_graph(), 4
     raise ValueError(

@@ -1,26 +1,49 @@
-"""Hunyuan-DiT-3B (paper's own model, scaled): 32 DiT blocks (16+16 with
-long skips), d=2048, 16 heads, d_ff=8192, adaLN time conditioning, text
-cross-attention (CLIP+T5 stub embeddings), latent 64x64x4.
+"""Hunyuan-DiT DiT-g/2 (Tencent, arXiv:2405.08748; ``HUNYUAN_DIT_CONFIG
+['DiT-g/2']`` of github.com/Tencent/HunyuanDiT, ``hydit/modules``) at its
+published widths: 40 blocks of d 1408, 16 heads of 88, MLP 6144, patch 2,
+4 latent channels in and 8 out, text cross-attention over 77 CLIP + 256
+mT5 tokens of width 1024; 1,499,952,032 parameters.  Trained at 1024 px:
+128x128x4 latents, 4,096 tokens.
+
+The PULSE path is ``python -m repro.launch.train --arch hunyuan-dit
+--pipeline``: ``pipeline_graph`` (the 21 + 19 blocks with their 19 long
+skips and the fold pinned to the stack boundary) and
+``pipeline_model_fns`` through ``auto_pipeline``.
+
+What the program assumes (``bench/configs/hydit_g2.json`` lists the
+same):
+
+- the objective is the program's ``ddpm_loss`` (cosine schedule, eps-MSE)
+  on the first 4 of the 8 output channels; the published schedule, its
+  prediction target and its variance term are not used, so the variance
+  channels get no gradient;
+- the v1.0 image-size and style conditions are left out (``size_cond``
+  and ``use_style_cond`` off), so the extra embedder reads the pooled mT5
+  states alone;
+- RoPE pairs adjacent head dims, the first half of the pairs turning with
+  the token's column and the second with its row, at positions
+  ``i * 32 / 64`` (a 512-px base grid); the mT5 MLP's hidden width is
+  8192 (4 x 2048): none of these changes a shape or a cost;
+- weights normal over sqrt(fan-in) and biases zero (not DiT's zero-init
+  of the modulation and output layers, so every leaf has a gradient at
+  the first step); the q/k/v projection is three matrices, the same map
+  as the published fused one.
 """
 import jax
 import jax.numpy as jnp
 from repro.configs.base import ArchBundle, ShapeSpec
 from repro.models import diffusion as dm
 from repro.models.diffusion import HunyuanDiTConfig
-from repro.runtime.pipeline import PipelineConfig
-from repro.runtime.adapters import (DiffusionPipelineAdapter,
-                                    make_diffusion_microbatches)
 from repro.train.steps import ParallelPlan
 
-CFG = HunyuanDiTConfig(
-    name="hunyuan-dit", img_size=64, in_ch=4, patch=2, d_model=2048,
-    n_layers=32, n_heads=16, d_ff=8192, ctx_dim=1024, ctx_len=77,
-    dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+#: at 4,096 tokens a P=4 stage's backward fits a v5e only with query-
+#: blocked attention and per-block remat (PERF.md, section 4)
+CFG = HunyuanDiTConfig(name="hunyuan-dit", q_block=1024,
+                       dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
 
 PLANS = {
-    "train_4k": ParallelPlan(strategy="pp_wave", pp_degree=16,
-                             microbatches=16, batch_axes=("pod", "data"),
-                             fsdp_axes=("data",)),
+    "train_4k": ParallelPlan(tp_axis=None, fsdp_axes=("model", "data"),
+                             batch_axes=("pod", "data")),
 }
 SUPPORT = {"train_4k": "ok",
            "prefill_32k": "n/a: diffusion training arch",
@@ -29,53 +52,31 @@ SUPPORT = {"train_4k": "ok",
 
 
 def batch_struct(shape: ShapeSpec, plan=None):
-    plan = plan or PLANS["train_4k"]
-    M = plan.microbatches
     B = shape.global_batch
+    sds = jax.ShapeDtypeStruct
     return {
-        "latents": jax.ShapeDtypeStruct((M, B // M, CFG.img_size,
-                                         CFG.img_size, CFG.in_ch),
-                                        jnp.bfloat16),
-        "text_embeds": jax.ShapeDtypeStruct((M, B // M, CFG.ctx_len,
-                                             CFG.ctx_dim), jnp.bfloat16),
+        "latents": sds((B, CFG.img_size, CFG.img_size, CFG.in_ch),
+                       jnp.bfloat16),
+        "clip": sds((B, CFG.clip_len, CFG.text_dim), jnp.bfloat16),
+        "t5": sds((B, CFG.t5_len, CFG.t5_dim), jnp.bfloat16),
+        "clip_len": sds((B,), jnp.int32),
+        "t5_len": sds((B,), jnp.int32),
     }
 
 
 def loss_fn(params, batch, rng):
-    flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in batch.items()}
-    return dm.hunyuan_loss(params, flat, rng, CFG)
+    return dm.hunyuan_loss(params, batch, rng, CFG)
 
 
-def make_adapter(plan: ParallelPlan, mesh):
-    axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    dp_axes = tuple(a for a in plan.batch_axes if a in axis_sizes)
-    dp = 1
-    for a in dp_axes:
-        dp *= axis_sizes[a]
-    pcfg = PipelineConfig(num_devices=axis_sizes["model"],
-                          num_microbatches=plan.microbatches,
-                          data_axes=dp_axes, dp_size=dp, remat=True)
-    return DiffusionPipelineAdapter(CFG, pcfg, "hunyuan")
+def pipeline_graph(batch: int = 1, fwd_times=None, cfg=CFG):
+    """Runtime-aligned block graph for the auto-pipeline compile path."""
+    return dm.hunyuan_pipeline_graph(cfg, batch, fwd_times=fwd_times)
 
 
-def make_microbatches(batch, rng, edge):
-    M, b = batch["latents"].shape[:2]
-    flat = {k: v.reshape((M * b,) + v.shape[2:]) for k, v in batch.items()}
-    mb, aux = make_diffusion_microbatches(flat, rng, M, CFG, "hunyuan",
-                                          params=edge)
-    return (mb, aux)
-
-
-def pipeline_graph(batch: int = 1, fwd_times=None):
-    """Runtime-aligned block graph for the auto-pipeline compile path
-    (one block per enc/dec row, fully-paired skips)."""
-    return dm.hunyuan_pipeline_graph(CFG, batch, fwd_times=fwd_times)
-
-
-def pipeline_model_fns():
+def pipeline_model_fns(cfg=CFG):
     """Block-level compile-path callables for this config's model."""
-    from repro.runtime.adapters import diffusion_model_fns
-    return diffusion_model_fns(CFG, "hunyuan")
+    from repro.runtime.adapters import hunyuan_model_fns
+    return hunyuan_model_fns(cfg)
 
 
 def auto_plan(N: int, **kwargs):
@@ -97,5 +98,5 @@ def get_bundle():
         loss_fn=loss_fn, batch_struct=batch_struct, plans=PLANS,
         shape_support=SUPPORT, param_count=CFG.param_count(),
         active_param_count=CFG.param_count(),
-        make_adapter=make_adapter, make_microbatches=make_microbatches,
-        notes="paper model; adaLN + cross-attn wave pipeline")
+        notes="paper model; pipelined through auto_pipeline (21+19 "
+              "stacks, fold pinned to their boundary)")

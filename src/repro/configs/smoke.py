@@ -9,6 +9,7 @@ output shapes.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.models import lm as lm_mod
 from repro.models import whisper as wh
@@ -131,16 +132,35 @@ def smoke_uvit():
             lambda k: dm.init_uvit(k, cfg), make_batch, cfg)
 
 
-def smoke_hunyuan():
-    cfg = HunyuanDiTConfig("hunyuan-smoke", img_size=8, in_ch=4, patch=2,
-                           d_model=32, n_layers=4, n_heads=4, d_ff=64,
-                           ctx_dim=16, ctx_len=7)
+def hunyuan_tiny(name: str = "hunyuan-smoke", n_layers: int = 4,
+                 **kw) -> HunyuanDiTConfig:
+    """Hunyuan-DiT's block at CPU-test widths: 8x8 latents (16 tokens),
+    d 32 in 4 heads of 8, text of 5 CLIP + 7 mT5 tokens."""
+    return HunyuanDiTConfig(name, img_size=8, in_ch=4, out_ch=8, patch=2,
+                            d_model=32, n_layers=n_layers, n_heads=4,
+                            d_ff=64, text_dim=16, clip_len=5, t5_dim=24,
+                            t5_len=7, t5_hidden=32, pool_heads=2,
+                            pool_dim=16, freq_dim=16, rope_base=2, **kw)
 
-    def make_batch(key):
-        return {"latents": jax.random.normal(key, (2, 8, 8, 4)),
-                "text_embeds": jax.random.normal(key, (2, 7, 16))}
+
+def hunyuan_tiny_batch(key, cfg: HunyuanDiTConfig, B: int = 2) -> dict:
+    """Latents, CLIP and mT5 states and valid counts for ``hunyuan_tiny``
+    (the counts leave padded slots in both parts)."""
+    k = jax.random.split(key, 3)
+    n5 = jnp.arange(B, dtype=jnp.int32) % cfg.t5_len + max(1, cfg.t5_len // 2)
+    return {"latents": jax.random.normal(k[0], (B, cfg.img_size,
+                                                cfg.img_size, cfg.in_ch)),
+            "clip": jax.random.normal(k[1], (B, cfg.clip_len, cfg.text_dim)),
+            "t5": jax.random.normal(k[2], (B, cfg.t5_len, cfg.t5_dim)),
+            "clip_len": jnp.minimum(n5 - 1, cfg.clip_len),
+            "t5_len": jnp.minimum(n5, cfg.t5_len)}
+
+
+def smoke_hunyuan():
+    cfg = hunyuan_tiny()
     return (lambda p, b, r: dm.hunyuan_loss(p, b, r, cfg),
-            lambda k: dm.init_hunyuan(k, cfg), make_batch, cfg)
+            lambda k: dm.init_hunyuan(k, cfg),
+            lambda key: hunyuan_tiny_batch(key, cfg), cfg)
 
 
 def smoke_sdv2():

@@ -42,12 +42,20 @@ class SkipEdge:
 class BlockGraph:
     blocks: tuple[Block, ...]
     skips: tuple[SkipEdge, ...] = ()
+    #: block index a folded partition must turn at (its prefix stages
+    #: cover exactly ``[0, turnaround)``), or None to let the partitioner
+    #: choose: a model whose blocks form two parameter stacks pins it to
+    #: their boundary (Hunyuan-DiT's blocks with and without skip-in)
+    turnaround: int | None = None
 
     def __post_init__(self):
         n = len(self.blocks)
         for e in self.skips:
             if not (0 <= e.src < n and 0 <= e.dst < n):
                 raise ValueError(f"skip edge {e} out of range for {n} blocks")
+        if self.turnaround is not None and not 0 < self.turnaround < n:
+            raise ValueError(f"turnaround {self.turnaround} is not inside "
+                             f"the {n} blocks")
 
     @property
     def n(self) -> int:

@@ -283,6 +283,11 @@ def _feasible_j_interval(graph: BlockGraph, i: int) -> tuple[int, int]:
             hi = min(hi, e.dst)
         else:
             lo = max(lo, e.dst + 1)
+    if graph.turnaround is not None:
+        # a pinned turnaround keeps the prefix before it, the suffix after
+        if i > graph.turnaround:
+            return 1, 0
+        lo = max(lo, graph.turnaround)
     return max(lo, i), hi
 
 
@@ -301,7 +306,10 @@ def partition_bidirectional(
     exponential :func:`partition_reference` is a test oracle, not a
     fallback.  For nested skips the interval collapses to the paper's
     state space, giving the O(p n^3) bound (and far less when most blocks
-    carry skips).
+    carry skips).  A graph's ``turnaround`` restricts the states to prefix
+    ends at or before it and suffix starts at or after it, so the closing
+    state (prefix end == suffix start) sits on it and the DP balances the
+    two sides under that constraint.
     """
     n = graph.n
     if p % 2 != 0:
@@ -409,6 +417,8 @@ def partition_reference(
         raise ValueError("reference partitioner assumes even stage count")
 
     def stage_symmetric(part: Partition) -> bool:
+        if graph.turnaround not in (None, part.cuts[p // 2]):
+            return False
         return all(
             part.stage_of_block(e.dst) == p - 1 - part.stage_of_block(e.src)
             for e in graph.skips)

@@ -10,6 +10,8 @@ time.
 """
 from __future__ import annotations
 
+import dataclasses
+
 from typing import Sequence
 
 from repro.core.graph import Block, BlockGraph
@@ -35,4 +37,5 @@ def analytic_block_costs(
 
 def reprofile_graph(graph: BlockGraph, hw: Hardware = TPU_V5E) -> BlockGraph:
     """Analytically re-profile every block of a graph for hardware ``hw``."""
-    return BlockGraph(analytic_block_costs(graph.blocks, hw), graph.skips)
+    return dataclasses.replace(graph,
+                               blocks=analytic_block_costs(graph.blocks, hw))

@@ -1,2 +1,2 @@
 from repro.data.pipeline import (SyntheticTokenDataset, SyntheticLatentDataset,
-                                 ShardedLoader)
+                                 SyntheticTextLatentDataset, ShardedLoader)

@@ -13,7 +13,8 @@ index so any host can (re)generate its shard independently):
 
 ``SyntheticTokenDataset`` draws from a fixed Markov chain so LM losses
 actually decrease; ``SyntheticLatentDataset`` mixes class/text-conditioned
-Gaussian modes so diffusion losses decrease.
+Gaussian modes so diffusion losses decrease; ``SyntheticTextLatentDataset``
+adds Hunyuan-DiT's CLIP and mT5 states with their valid-token counts.
 """
 from __future__ import annotations
 
@@ -79,6 +80,55 @@ class SyntheticLatentDataset:
         if self.text_dim:
             out["text_embeds"] = self.text_bank[labels]
         return out
+
+
+@dataclasses.dataclass
+class SyntheticTextLatentDataset:
+    """Text-conditioned latents (Hunyuan-DiT's inputs): each class a fixed
+    Gaussian latent mode and fixed CLIP and mT5 states; each sample its
+    class's mode plus 0.3 noise and its class's text states.  The mT5
+    valid-token count is lognormal around ``t5_typical`` (sigma 0.5),
+    clipped to ``[min(8, t5_len), t5_len]``, and CLIP's is that count
+    clipped to ``clip_len``: the model puts its learned padding past them,
+    so the counts change values, not work."""
+
+    img_size: int
+    channels: int
+    clip_len: int = 77
+    clip_dim: int = 1024
+    t5_len: int = 256
+    t5_dim: int = 2048
+    n_classes: int = 10
+    t5_typical: int = 96
+    seed: int = 0
+
+    def __post_init__(self):
+        rng = np.random.default_rng(self.seed)
+        self.modes = rng.normal(
+            0, 1, size=(self.n_classes, self.img_size, self.img_size,
+                        self.channels)).astype(np.float32)
+        self.clip_bank = rng.normal(
+            0, 1, size=(self.n_classes, self.clip_len, self.clip_dim)
+        ).astype(np.float32)
+        self.t5_bank = rng.normal(
+            0, 1, size=(self.n_classes, self.t5_len, self.t5_dim)
+        ).astype(np.float32)
+
+    def batch(self, step: int, host_id: int, batch: int) -> dict:
+        rng = np.random.default_rng(
+            (self.seed * 1_000_003 + step) * 65_539 + host_id)
+        labels = rng.integers(0, self.n_classes, size=batch).astype(np.int32)
+        lat = (self.modes[labels]
+               + 0.3 * rng.normal(0, 1, size=(batch, self.img_size,
+                                              self.img_size, self.channels))
+               ).astype(np.float32)
+        t5_len = np.clip(np.rint(rng.lognormal(np.log(self.t5_typical), 0.5,
+                                               size=batch)),
+                         min(8, self.t5_len), self.t5_len).astype(np.int32)
+        return {"latents": lat, "labels": labels,
+                "clip": self.clip_bank[labels], "t5": self.t5_bank[labels],
+                "clip_len": np.minimum(t5_len, self.clip_len),
+                "t5_len": t5_len}
 
 
 @dataclasses.dataclass

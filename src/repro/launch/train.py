@@ -53,6 +53,8 @@ Usage:
         --devices 8 --dp 2 --steps 50   # wave PP over 8 simulated devices
     PYTHONPATH=src python -m repro.launch.train --arch uvit-h --pipeline \
         --layers 8 --steps 10           # UViT-H widths on the devices present
+    PYTHONPATH=src python -m repro.launch.train --arch hunyuan-dit \
+        --pipeline --global-batch 8 --microbatches 8   # DiT-g/2, 4 chips
 """
 import argparse
 import dataclasses
@@ -64,7 +66,8 @@ from typing import Any
 #: fold_in(PRNGKey(SEED), k) — what a reference run rebuilds to compare
 SEED = 0
 #: ``--arch`` values of the pipeline path (see :func:`pipeline_config`)
-PIPELINE_ARCHS = ("uvit", "skipvit", "uvit-nano", "uvit-h")
+PIPELINE_ARCHS = ("uvit", "skipvit", "uvit-nano", "uvit-h", "hunyuan",
+                  "hunyuan-dit")
 
 
 def _parse_args(argv=None):
@@ -73,8 +76,9 @@ def _parse_args(argv=None):
                     help="smoke arch key (see repro.configs.smoke); with "
                          f"--pipeline one of {PIPELINE_ARCHS}")
     ap.add_argument("--layers", type=int, default=None,
-                    help="pipeline path: depth cut (total enc + dec "
-                         "blocks) of --arch uvit-h; widths stay published")
+                    help="pipeline path: depth cut (total blocks) of "
+                         "--arch uvit-h or hunyuan-dit; widths stay "
+                         "published")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--global-batch", type=int, default=16)
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -405,7 +409,7 @@ def _build_smoke_trainer(args, key, opt_cfg):
     from repro.configs.smoke import SMOKE_FACTORIES
     from repro.optim import adamw_init, adamw_update
     from repro.data import SyntheticLatentDataset, SyntheticTokenDataset, \
-        ShardedLoader
+        SyntheticTextLatentDataset, ShardedLoader
     from repro.runtime.resilience import all_finite
 
     name = args.arch if args.arch in SMOKE_FACTORIES else {
@@ -414,7 +418,13 @@ def _build_smoke_trainer(args, key, opt_cfg):
     params = init_fn(key)
     opt_state = adamw_init(params)
     proto = make_batch(key)
-    if "latents" in proto:
+    if "t5" in proto:
+        ds = SyntheticTextLatentDataset(
+            img_size=proto["latents"].shape[1],
+            channels=proto["latents"].shape[-1],
+            clip_len=proto["clip"].shape[1], clip_dim=proto["clip"].shape[2],
+            t5_len=proto["t5"].shape[1], t5_dim=proto["t5"].shape[2])
+    elif "latents" in proto:
         ds = SyntheticLatentDataset(
             img_size=proto["latents"].shape[1],
             channels=proto["latents"].shape[-1],
@@ -457,20 +467,26 @@ def pipeline_config(arch: str, layers: int | None = None):
     The small archs are fixed (independent of the mesh shape) so a
     checkpoint from one (pp, dp, zero, V) plan restores elastically onto
     any other; they keep the multi-device CPU drills fast.  ``uvit-h`` is
-    the paper's UViT-H (``configs/uvit_h.CFG``) at its published widths;
-    ``layers`` cuts its depth only.
+    the paper's UViT-H (``configs/uvit_h.CFG``) and ``hunyuan-dit``
+    Hunyuan-DiT DiT-g/2 (``configs/hunyuan_dit.CFG``) at their published
+    widths; ``layers`` cuts their depth only.
     """
     from repro.models.diffusion import SkipViTConfig, UViTConfig
-    if arch == "uvit-h":
-        from repro.configs.uvit_h import CFG
+    if arch in ("uvit-h", "hunyuan-dit"):
+        if arch == "uvit-h":
+            from repro.configs.uvit_h import CFG
+        else:
+            from repro.configs.hunyuan_dit import CFG
         if layers is None:
             return CFG
-        if layers < 2 or layers % 2:
-            raise ValueError(f"--layers {layers}: UViT-H needs an even "
-                             "block count (half encoder, half decoder)")
+        least = 2 if arch == "uvit-h" else 4
+        if layers < least or layers % 2:
+            raise ValueError(f"--layers {layers}: {arch} needs an even "
+                             f"block count of at least {least}")
         return dataclasses.replace(CFG, n_layers=layers)
     if layers is not None:
-        raise ValueError("--layers cuts the depth of --arch uvit-h only")
+        raise ValueError("--layers cuts the depth of --arch uvit-h or "
+                         "hunyuan-dit only")
     if arch == "skipvit":
         return SkipViTConfig("skipvit-pp", img_size=8, in_ch=4, patch=2,
                              d_model=64, n_heads=4, d_ff=128, n_classes=10,
@@ -485,16 +501,30 @@ def pipeline_config(arch: str, layers: int | None = None):
         return UViTConfig("uvit-pp", img_size=8, in_ch=4, patch=2,
                           d_model=64, n_layers=8, n_heads=4, d_ff=128,
                           n_classes=10)
+    if arch == "hunyuan":
+        # Hunyuan-DiT's block at CPU-drill widths, depth 8 (5 + 3 blocks)
+        from repro.configs.smoke import hunyuan_tiny
+        return hunyuan_tiny("hunyuan-pp", n_layers=8)
     raise ValueError(f"unknown pipeline arch {arch!r}; choose from "
                      f"{PIPELINE_ARCHS}")
 
 
 def pipeline_loader(cfg, global_batch: int):
     """The pipeline path's data: class-conditioned synthetic latents at
-    the config's resolution, channels and class count."""
-    from repro.data import ShardedLoader, SyntheticLatentDataset
-    ds = SyntheticLatentDataset(img_size=cfg.img_size, channels=cfg.in_ch,
-                                n_classes=cfg.n_classes)
+    the config's resolution, channels and class count; for Hunyuan-DiT,
+    text-conditioned ones with its CLIP and mT5 states."""
+    from repro.data import (ShardedLoader, SyntheticLatentDataset,
+                            SyntheticTextLatentDataset)
+    from repro.models.diffusion import HunyuanDiTConfig
+    if isinstance(cfg, HunyuanDiTConfig):
+        ds = SyntheticTextLatentDataset(
+            img_size=cfg.img_size, channels=cfg.in_ch,
+            clip_len=cfg.clip_len, clip_dim=cfg.text_dim,
+            t5_len=cfg.t5_len, t5_dim=cfg.t5_dim)
+    else:
+        ds = SyntheticLatentDataset(img_size=cfg.img_size,
+                                    channels=cfg.in_ch,
+                                    n_classes=cfg.n_classes)
     return ShardedLoader(ds, global_batch=global_batch)
 
 
@@ -556,11 +586,12 @@ def pipeline_plan(args):
     dp), ZeRO stage, interleave, microbatches and wire dtype."""
     import jax
 
-    from repro.models.diffusion import (SkipViTConfig,
+    from repro.models.diffusion import (HunyuanDiTConfig, SkipViTConfig,
+                                        hunyuan_pipeline_graph,
                                         skipvit_pipeline_graph,
                                         uvit_pipeline_graph)
     from repro.runtime.adapters import diffusion_model_fns, \
-        skipvit_model_fns
+        hunyuan_model_fns, skipvit_model_fns
     from repro.runtime.compile import auto_pipeline
 
     cfg = pipeline_config(args.arch, args.layers)
@@ -570,6 +601,9 @@ def pipeline_plan(args):
     if isinstance(cfg, SkipViTConfig):
         graph, fns = skipvit_pipeline_graph(cfg, batch=b), \
             skipvit_model_fns(cfg)
+    elif isinstance(cfg, HunyuanDiTConfig):
+        graph, fns = hunyuan_pipeline_graph(cfg, batch=b), \
+            hunyuan_model_fns(cfg)
     else:
         graph, fns = uvit_pipeline_graph(cfg, batch=b), \
             diffusion_model_fns(cfg, "uvit")
@@ -585,6 +619,9 @@ def pipeline_step(compiled, cfg, mesh, opt_cfg):
     """``(step, shardings)``: the jitted train step of the pipeline path
     on ``mesh`` — pipelined loss and grads, the non-finite guard, AdamW —
     and the shardings of its ``(params, opt_state, batch, rng, lr)``.
+    The microbatches and their ``aux`` follow the config's model kind
+    (``adapters.pipeline_microbatches``): Hunyuan-DiT's conditioning is
+    computed from the edge parameters once a step, outside the executor.
 
     Stage stacks sit over ``"model"`` (ZeRO-2: one block dim over
     ``"data"`` too), edge params and inputs are replicated, and the step
@@ -596,14 +633,15 @@ def pipeline_step(compiled, cfg, mesh, opt_cfg):
 
     from repro.optim import adamw_update
     from repro.runtime import scopes
-    from repro.runtime.adapters import make_diffusion_microbatches
+    from repro.runtime.adapters import pipeline_microbatches
     from repro.runtime.resilience import all_finite
 
     M = compiled.pcfg.num_microbatches
     loss_of_mb = compiled.bind(mesh)
+    microbatches = pipeline_microbatches(cfg)
 
     def loss_of(params, batch, rng):
-        mb, aux = make_diffusion_microbatches(batch, rng, M, cfg, "uvit")
+        mb, aux = microbatches(params[1], batch, rng, M)
         return loss_of_mb(params, mb, aux)
 
     def step(params, opt_state, batch, rng, lr):

@@ -106,8 +106,9 @@ class UViTConfig:
                 + self.n_classes * d + self.patch ** 2 * self.in_ch * d * 2)
 
 
-def _init_vit_block(key, cfg, d_ff: int, with_skip: bool,
-                    cross_dim: int = 0, ada: bool = False) -> Params:
+def _init_vit_block(key, cfg, d_ff: int, with_skip: bool) -> Params:
+    # six keys, of which three are used: the split fixes every value
+    # drawn from them (and bench/reference/uvit.py draws them alike)
     ks = jax.random.split(key, 6)
     d, pd = cfg.d_model, cfg.param_dtype
     p: Params = {
@@ -118,28 +119,25 @@ def _init_vit_block(key, cfg, d_ff: int, with_skip: bool,
     }
     if with_skip:
         p["skip_proj"] = L.dense_init(ks[2], 2 * d, d, pd)
-    if cross_dim:
-        p["lnx"] = jnp.ones((d,), pd)
-        p["xattn"] = L.init_attention(ks[3], cfg.attn_cfg(), pd)
-        p["ctx_kv"] = L.dense_init(ks[4], cross_dim, 2 * d, pd)
-    if ada:
-        p["ada"] = (jax.random.normal(ks[5], (d, 6 * d)) * 0.02 / math.sqrt(d)
-                    ).astype(pd)
     return p
 
 
 @jax.named_scope(scopes.SKIP_PROJ)
 def _skip_project(p: Params, x: Array, skip: Array, cfg) -> Array:
-    """Decoder skip-in projection: ``y = [x | skip] @ skip_proj``.
+    """Decoder skip-in projection: ``y = [x | skip] @ skip_proj``; where
+    the block has them, a LayerNorm over ``[x | skip]`` first
+    (``skip_norm``) and a bias after (``skip_bias``), as Hunyuan-DiT.
 
     With ``cfg.use_skip_kernel`` the fused Pallas kernel
     (``h @ W1 + s @ W2``, f32 accumulation; interpret mode on the CPU)
     replaces the concat matmul — the concat materialises the ``(.., 2D)``
-    activation in HBM just to read it back once.  Operand shapes that do
-    not tile the kernel's 128-square MXU blocks raise: a caller that asks
-    for the kernel gets it, never a silent switch to the reference.
+    activation in HBM just to read it back once (a ``skip_norm`` needs it
+    all the same).  Operand shapes that do not tile the kernel's
+    128-square MXU blocks raise: a caller that asks for the kernel gets
+    it, never a silent switch to the reference.
     """
     w = p["skip_proj"].astype(x.dtype)
+    norm = p.get("skip_norm")
     if getattr(cfg, "use_skip_kernel", False):
         rows = math.prod(x.shape[:-1])
         if not skip_concat_matmul_supported(rows, x.shape[-1], w.shape[1]):
@@ -148,37 +146,30 @@ def _skip_project(p: Params, x: Array, skip: Array, cfg) -> Array:
                 f"x ({w.shape[0]}, {w.shape[1]}) do not tile the fused "
                 "kernel's blocks (rows = tokens x batch must be a multiple "
                 "of 128 or at most 128)")
-        return skip_concat_matmul(x, skip.astype(x.dtype), w)
-    return jnp.concatenate([x, skip], axis=-1) @ w
+        if norm is not None:
+            h = L.layer_norm(jnp.concatenate([x, skip], axis=-1),
+                             norm["scale"], norm["bias"], cfg.norm_eps)
+            x, skip = jnp.split(h, 2, axis=-1)
+        y = skip_concat_matmul(x, skip.astype(x.dtype), w)
+    else:
+        h = jnp.concatenate([x, skip], axis=-1)
+        if norm is not None:
+            h = L.layer_norm(h, norm["scale"], norm["bias"], cfg.norm_eps)
+        y = h @ w
+    if "skip_bias" in p:
+        y = y + p["skip_bias"].astype(y.dtype)
+    return y
 
 
-def _apply_vit_block(p: Params, x: Array, cfg, *, skip: Array | None = None,
-                     ctx: Array | None = None, temb: Array | None = None
+def _apply_vit_block(p: Params, x: Array, cfg, *, skip: Array | None = None
                      ) -> Array:
     if skip is not None:
         x = _skip_project(p, x, skip, cfg)
-    if temb is not None and "ada" in p:
-        mods = (jax.nn.silu(temb) @ p["ada"].astype(temb.dtype))[:, None]
-        s1, b1, g1, s2, b2, g2 = jnp.split(mods, 6, axis=-1)
-    else:
-        s1 = b1 = s2 = b2 = 0.0
-        g1 = g2 = 1.0
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps) * (1 + s1) + b1
+    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     a, _ = L.apply_attention(p["attn"], h, cfg.attn_cfg())
-    x = x + g1 * a
-    if ctx is not None and "xattn" in p:
-        h = L.rms_norm(x, p["lnx"], cfg.norm_eps)
-        kv = ctx @ p["ctx_kv"].astype(ctx.dtype)
-        d = cfg.d_model
-        B, T = ctx.shape[0], ctx.shape[1]
-        hd = cfg.attn_cfg().head_dim
-        kx = kv[..., :d].reshape(B, T, cfg.n_heads, hd)
-        vx = kv[..., d:].reshape(B, T, cfg.n_heads, hd)
-        a, _ = L.apply_attention(p["xattn"], h, cfg.attn_cfg(),
-                                 cross_kv=(kx, vx))
-        x = x + a
-    h = L.rms_norm(x, p["ln2"], cfg.norm_eps) * (1 + s2) + b2
-    return x + g2 * L.apply_gelu_mlp(p["mlp"], h)
+    x = x + a
+    h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+    return x + L.apply_gelu_mlp(p["mlp"], h)
 
 
 def init_uvit(key, cfg: UViTConfig) -> Params:
@@ -282,22 +273,44 @@ def uvit_block_graph(cfg: UViTConfig, batch: int,
 
 
 # --------------------------------------------------------------------------
-# Hunyuan-DiT (paper [7]): DiT with adaLN + text cross-attention + skips
+# Hunyuan-DiT (paper [7]): DiT-g/2 with text cross-attention + long skips
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class HunyuanDiTConfig:
+    """Hunyuan-DiT (Tencent, arXiv:2405.08748), ``HUNYUAN_DIT_CONFIG
+    ['DiT-g/2']`` at its published widths by default.
+
+    ``n_layers`` blocks; the first ``n_layers // 2 - 1`` push their output
+    as a long skip, blocks ``> n_layers // 2`` pop one (through a LayerNorm
+    over ``[x | skip]`` and a linear), and the two blocks between are
+    neither.  So the parameters form two stacks: ``enc_blocks`` (the
+    ``n_layers // 2 + 1`` blocks without skip parameters) and
+    ``dec_blocks`` (the rest), and a pipeline's fold must turn on the
+    boundary between them (:func:`hunyuan_pipeline_graph`).
+    """
+
     name: str
-    img_size: int = 64
+    img_size: int = 128           # latent side (1024 px through the SD VAE)
     in_ch: int = 4
+    out_ch: int = 8               # learn_sigma: eps and variance channels
     patch: int = 2
-    d_model: int = 1024
-    n_layers: int = 24
+    d_model: int = 1408
+    n_layers: int = 40
     n_heads: int = 16
-    d_ff: int = 4096
-    ctx_dim: int = 1024           # CLIP+T5 text embedding dim (stub input)
-    ctx_len: int = 77
+    d_ff: int = 6144              # int(1408 * 4.3637)
+    text_dim: int = 1024          # CLIP width = cross-attention context
+    clip_len: int = 77
+    t5_dim: int = 2048            # mT5-XL states
+    t5_len: int = 256
+    t5_hidden: int = 8192         # mlp_t5: t5_dim -> t5_hidden -> text_dim
+    pool_heads: int = 8           # AttentionPool over the mT5 states
+    pool_dim: int = 1024          # its output width
+    freq_dim: int = 256           # sinusoidal timestep features
+    rope_theta: float = 10000.0
+    rope_base: int = 32           # RoPE grid of a 512-px image (512/8/2)
     norm_eps: float = 1e-6
+    q_block: int | None = None    # query-blocked attention (L.attention)
     use_skip_kernel: bool = False  # fused Pallas skip-in (see _skip_project)
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -307,91 +320,295 @@ class HunyuanDiTConfig:
         return (self.img_size // self.patch) ** 2
 
     @property
-    def half(self) -> int:
-        return self.n_layers // 2
+    def text_len(self) -> int:
+        return self.clip_len + self.t5_len
 
-    def attn_cfg(self) -> AttnConfig:
-        return AttnConfig(self.d_model, self.n_heads, self.n_heads,
-                          self.d_model // self.n_heads, rope_theta=0.0,
-                          causal=False)
+    @property
+    def n_enc(self) -> int:
+        """Blocks without skip parameters (``enc_blocks`` rows)."""
+        return self.n_layers // 2 + 1
+
+    @property
+    def n_skips(self) -> int:
+        """Long skips: block ``i`` feeds block ``n_layers - 1 - i``."""
+        return self.n_layers // 2 - 1
+
+    def _attn(self, **kw) -> AttnConfig:
+        hd = self.d_model // self.n_heads
+        grid = self.img_size // self.patch
+        return AttnConfig(self.d_model, self.n_heads, self.n_heads, hd,
+                          rope_theta=self.rope_theta, causal=False,
+                          qk_norm=True, qk_norm_kind="layer", bias=True,
+                          rope_2d=(grid, self.rope_base),
+                          q_block=self.q_block, **kw)
+
+    def self_attn_cfg(self) -> AttnConfig:
+        return self._attn()
+
+    def cross_attn_cfg(self) -> AttnConfig:
+        return self._attn(kv_dim=self.text_dim, rope_cross_q=True)
 
     def param_count(self) -> int:
-        d = self.d_model
-        per = 4 * d * d + 2 * d * self.d_ff + 4 * d * d + 6 * d * d \
-            + self.ctx_dim * 2 * d
-        return self.n_layers * per + self.half * 2 * d * d
+        d, ff, hd = self.d_model, self.d_ff, self.d_model // self.n_heads
+        lin = lambda i, o: i * o + o
+        block = (3 * 2 * d + lin(d, 3 * d) + lin(d, d) + 4 * 2 * hd
+                 + lin(d, d) + lin(d, d) + lin(self.text_dim, 2 * d)
+                 + lin(d, d) + lin(d, ff) + lin(ff, d))
+        skip = 2 * 2 * d + lin(2 * d, d)
+        edge = (lin(self.patch ** 2 * self.in_ch, d)
+                + lin(self.freq_dim, d) + lin(d, d)
+                + (self.t5_len + 1) * self.t5_dim
+                + 3 * lin(self.t5_dim, self.t5_dim)
+                + lin(self.t5_dim, self.pool_dim)
+                + lin(self.pool_dim, 4 * d) + lin(4 * d, d)
+                + lin(self.t5_dim, self.t5_hidden)
+                + lin(self.t5_hidden, self.text_dim)
+                + self.text_len * self.text_dim
+                + lin(d, 2 * d) + lin(d, self.patch ** 2 * self.out_ch))
+        return self.n_layers * block + (self.n_layers - self.n_enc) * skip \
+            + edge
+
+
+def _ln_init(d: int, dtype) -> Params:
+    return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+
+
+def _linear_init(key, d_in: int, d_out: int, dtype) -> Params:
+    return {"w": L.dense_init(key, d_in, d_out, dtype),
+            "b": jnp.zeros((d_out,), dtype)}
+
+
+def _linear(p: Params, x: Array) -> Array:
+    return x @ p["w"].astype(x.dtype) + p["b"].astype(x.dtype)
+
+
+def _ln(p: Params, x: Array, eps: float) -> Array:
+    return L.layer_norm(x, p["scale"], p["bias"], eps)
+
+
+def _init_dit_block(key, cfg: HunyuanDiTConfig, with_skip: bool) -> Params:
+    ks = jax.random.split(key, 5)
+    d, pd = cfg.d_model, cfg.param_dtype
+    p: Params = {
+        "norm1": _ln_init(d, pd),
+        "attn1": L.init_attention(ks[0], cfg.self_attn_cfg(), pd),
+        "mod": _linear_init(ks[1], d, d, pd),
+        "norm3": _ln_init(d, pd),
+        "attn2": L.init_attention(ks[2], cfg.cross_attn_cfg(), pd),
+        "norm2": _ln_init(d, pd),
+        "mlp": L.init_gelu_mlp(ks[3], d, cfg.d_ff, pd),
+    }
+    if with_skip:
+        p["skip_norm"] = _ln_init(2 * d, pd)
+        p["skip_proj"] = L.dense_init(ks[4], 2 * d, d, pd)
+        p["skip_bias"] = jnp.zeros((d,), pd)
+    return p
 
 
 def init_hunyuan(key, cfg: HunyuanDiTConfig) -> Params:
-    ks = jax.random.split(key, 6)
+    """Every weight normal over sqrt(fan-in), biases zero, norms one and
+    zero; the pooler's position table and the learned text padding normal
+    as published (the position table over sqrt of its width)."""
+    ks = jax.random.split(key, 14)
     d, pd = cfg.d_model, cfg.param_dtype
-    pp = cfg.patch ** 2 * cfg.in_ch
-    ek = jax.random.split(ks[0], cfg.half)
-    dk = jax.random.split(ks[1], cfg.half)
-    mk = lambda k, skip: _init_vit_block(k, cfg, cfg.d_ff, skip,
-                                         cross_dim=cfg.ctx_dim, ada=True)
+    c5 = cfg.t5_dim
+    pk = jax.random.split(ks[4], 4)
     return {
-        "patch_embed": L.dense_init(ks[2], pp, d, pd),
-        "pos_embed": (jax.random.normal(ks[3], (cfg.n_tokens, d)) * 0.02
-                      ).astype(pd),
-        "time_mlp": L.init_gelu_mlp(ks[4], d, 4 * d, pd),
-        "enc_blocks": jax.vmap(lambda k: mk(k, False))(ek),
-        "dec_blocks": jax.vmap(lambda k: mk(k, True))(dk),
-        "out_norm": jnp.ones((d,), pd),
-        "out_proj": L.dense_init(ks[5], d, pp, pd),
+        "x_embed": _linear_init(ks[2], cfg.patch ** 2 * cfg.in_ch, d, pd),
+        "t_embed": {"fc1": _linear_init(ks[3], cfg.freq_dim, d, pd),
+                    "fc2": _linear_init(ks[5], d, d, pd)},
+        "pooler": {
+            "pos": (jax.random.normal(ks[6], (cfg.t5_len + 1, c5))
+                    / math.sqrt(c5)).astype(pd),
+            "q": _linear_init(pk[0], c5, c5, pd),
+            "k": _linear_init(pk[1], c5, c5, pd),
+            "v": _linear_init(pk[2], c5, c5, pd),
+            "out": _linear_init(pk[3], c5, cfg.pool_dim, pd)},
+        "extra": {"fc1": _linear_init(ks[7], cfg.pool_dim, 4 * d, pd),
+                  "fc2": _linear_init(ks[8], 4 * d, d, pd)},
+        "t5_mlp": {"fc1": _linear_init(ks[9], c5, cfg.t5_hidden, pd),
+                   "fc2": _linear_init(ks[10], cfg.t5_hidden,
+                                       cfg.text_dim, pd)},
+        "text_pad": jax.random.normal(
+            ks[11], (cfg.text_len, cfg.text_dim)).astype(pd),
+        "final": {"mod": _linear_init(ks[12], d, 2 * d, pd),
+                  "proj": _linear_init(ks[13], d,
+                                       cfg.patch ** 2 * cfg.out_ch, pd)},
+        "enc_blocks": jax.vmap(lambda k: _init_dit_block(k, cfg, False))(
+            jax.random.split(ks[0], cfg.n_enc)),
+        "dec_blocks": jax.vmap(lambda k: _init_dit_block(k, cfg, True))(
+            jax.random.split(ks[1], cfg.n_layers - cfg.n_enc)),
     }
+
+
+def _attention_pool(p: Params, x: Array, heads: int) -> Array:
+    """CLIP's AttentionPool: the mean token, with a position table, queries
+    every token (mean included) with ``heads`` heads."""
+    B, T, C = x.shape
+    x = jnp.concatenate([jnp.mean(x, axis=1, keepdims=True), x], axis=1)
+    x = x + p["pos"].astype(x.dtype)[None]
+    hd = C // heads
+    q = _linear(p["q"], x[:, :1]).reshape(B, 1, heads, hd)
+    # the key bias adds one logit to every key, which the softmax drops:
+    # its gradient is zero, not rounding noise
+    pk = {**p["k"], "b": jax.lax.stop_gradient(p["k"]["b"])}
+    k = _linear(pk, x).reshape(B, T + 1, heads, hd)
+    v = _linear(p["v"], x).reshape(B, T + 1, heads, hd)
+    out = L.attention(q, k, v, causal=False)
+    return _linear(p["out"], out.reshape(B, C))
+
+
+def hunyuan_condition(params: Params, t: Array, batch: dict,
+                      cfg: HunyuanDiTConfig) -> tuple[Array, Array]:
+    """``(c, text)``: the conditioning vector ``[B, d]`` (timestep MLP plus
+    the extra embedder over the pooled mT5 states) and the
+    ``[B, clip_len + t5_len, text_dim]`` text tokens (CLIP states, then
+    the mT5 states through ``mlp_t5``; slots past each sample's valid
+    count take the learned padding).  ``batch``: ``clip``, ``t5`` and
+    the valid counts ``clip_len``, ``t5_len``."""
+    dt = cfg.dtype
+    silu = lambda h: jax.nn.silu(h.astype(jnp.float32)).astype(dt)
+    te = params["t_embed"]
+    c = _linear(te["fc2"], silu(_linear(
+        te["fc1"], timestep_embedding(t, cfg.freq_dim).astype(dt))))
+    t5 = batch["t5"].astype(dt)
+    ex = params["extra"]
+    pooled = _attention_pool(params["pooler"], t5, cfg.pool_heads)
+    c = c + _linear(ex["fc2"], silu(_linear(ex["fc1"], pooled)))
+    tm = params["t5_mlp"]
+    text = jnp.concatenate(
+        [batch["clip"].astype(dt),
+         _linear(tm["fc2"], silu(_linear(tm["fc1"], t5)))], axis=1)
+    valid = jnp.concatenate(
+        [jnp.arange(cfg.clip_len)[None] < batch["clip_len"][:, None],
+         jnp.arange(cfg.t5_len)[None] < batch["t5_len"][:, None]], axis=1)
+    text = jnp.where(valid[..., None], text,
+                     params["text_pad"].astype(dt)[None])
+    return c, text
+
+
+def hunyuan_embed(params: Params, xt: Array, cfg: HunyuanDiTConfig
+                  ) -> Array:
+    """Patch embedding (no absolute positions: the blocks' RoPE)."""
+    return _linear(params["x_embed"],
+                   _patchify(xt.astype(cfg.dtype), cfg.patch))
+
+
+def apply_dit_block(p: Params, x: Array, cfg: HunyuanDiTConfig, c: Array,
+                    text: Array, *, skip: Array | None = None) -> Array:
+    """One Hunyuan-DiT block: skip-in (blocks that take one), self-
+    attention on the shift-modulated LayerNorm, cross-attention to the
+    text, tanh-GELU MLP."""
+    eps = cfg.norm_eps
+    if skip is not None:
+        x = _skip_project(p, x, skip, cfg)
+    shift = _linear(p["mod"], jax.nn.silu(c.astype(x.dtype)))[:, None]
+    a, _ = L.apply_attention(p["attn1"], _ln(p["norm1"], x, eps) + shift,
+                             cfg.self_attn_cfg())
+    x = x + a
+    a, _ = L.apply_attention(p["attn2"], _ln(p["norm3"], x, eps),
+                             cfg.cross_attn_cfg(),
+                             context=text.astype(x.dtype))
+    x = x + a
+    return x + L.apply_gelu_mlp(p["mlp"], _ln(p["norm2"], x, eps))
+
+
+def hunyuan_output(params: Params, x: Array, c: Array,
+                   cfg: HunyuanDiTConfig) -> Array:
+    """Final layer: adaLN (shift, scale) on an affine-free LayerNorm, the
+    output projection, unpatchified to ``[B, H, W, out_ch]``."""
+    f = params["final"]
+    shift, scale = jnp.split(
+        _linear(f["mod"], jax.nn.silu(c.astype(x.dtype))), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    h = (xf - mu) * jax.lax.rsqrt(jnp.var(xf, axis=-1, keepdims=True)
+                                  + cfg.norm_eps)
+    h = (h * (1 + scale[:, None]) + shift[:, None]).astype(x.dtype)
+    return _unpatchify(_linear(f["proj"], h), cfg.patch, cfg.img_size,
+                       cfg.out_ch)
+
+
+def hunyuan_eps_loss(pred: Array, noise: Array, cfg: HunyuanDiTConfig
+                     ) -> Array:
+    """``ddpm_loss``'s eps-MSE on the first ``in_ch`` output channels (the
+    variance channels get no gradient from it)."""
+    return jnp.mean(jnp.square(pred[..., :cfg.in_ch].astype(jnp.float32)
+                               - noise.astype(jnp.float32)))
 
 
 def hunyuan_apply(params: Params, xt: Array, t: Array, batch: dict,
                   cfg: HunyuanDiTConfig) -> Array:
-    tok = _patchify(xt.astype(cfg.dtype), cfg.patch) @ params["patch_embed"].astype(cfg.dtype)
-    x = tok + params["pos_embed"].astype(cfg.dtype)[None]
-    temb = L.apply_gelu_mlp(params["time_mlp"],
-                            timestep_embedding(t, cfg.d_model).astype(cfg.dtype))
-    ctx = batch["text_embeds"].astype(cfg.dtype)
+    """Single-device forward; the pipelined path must match it."""
+    c, text = hunyuan_condition(params, t, batch, cfg)
+    x = hunyuan_embed(params, xt, cfg)
 
     def enc(x, bp):
-        x = _apply_vit_block(bp, x, cfg, ctx=ctx, temb=temb)
+        x = apply_dit_block(bp, x, cfg, c, text)
         return x, x
 
-    x, skips = jax.lax.scan(enc, x, params["enc_blocks"])
+    x, outs = jax.lax.scan(enc, x, params["enc_blocks"])
+    skips = outs[:cfg.n_skips]
 
     def dec(x, inp):
         bp, skip = inp
-        return _apply_vit_block(bp, x, cfg, skip=skip, ctx=ctx, temb=temb), None
+        return apply_dit_block(bp, x, cfg, c, text, skip=skip), None
 
+    # block n_enc + j takes the skip of block n_skips - 1 - j
     x, _ = jax.lax.scan(dec, x, (params["dec_blocks"], skips[::-1]))
-    x = L.rms_norm(x, params["out_norm"], cfg.norm_eps)
-    pix = x @ params["out_proj"].astype(x.dtype)
-    return _unpatchify(pix, cfg.patch, cfg.img_size, cfg.in_ch)
+    return hunyuan_output(params, x, c, cfg)
 
 
 def hunyuan_loss(params: Params, batch: dict, rng: Array,
                  cfg: HunyuanDiTConfig) -> Array:
-    return ddpm_loss(lambda p, xt, t, b: hunyuan_apply(p, xt, t, b, cfg),
-                     params, batch, rng)
+    return ddpm_loss(
+        lambda p, xt, t, b: hunyuan_apply(p, xt, t, b, cfg)[..., :cfg.in_ch],
+        params, batch, rng)
 
 
 def hunyuan_block_graph(cfg: HunyuanDiTConfig, batch: int,
                         hw: Hardware = TPU_V5E) -> BlockGraph:
-    d, n, ff, lt = cfg.d_model, cfg.n_tokens, cfg.d_ff, cfg.ctx_len
+    """Hunyuan-DiT with its embedding and head as blocks (analytic comm
+    studies): ``embed``, the ``n_layers`` blocks, ``out``; long skips
+    from block ``i`` to ``n_layers - 1 - i``."""
+    d, n = cfg.d_model, cfg.n_tokens
     act = batch * n * d * 2
-    blk_fl = 2 * batch * (4 * n * d * d + 2 * n * n * d + 2 * n * d * ff
-                          + 2 * n * d * d + cfg.ctx_dim * 2 * d * lt
-                          + 2 * n * lt * d + 6 * n * d * d // n)
-    per_param = (4 * d * d + 2 * d * ff + 2 * d * d + cfg.ctx_dim * 2 * d
-                 + 6 * d * d) * 2
-    blocks = [Block("embed", 0.0, d * 8, act, 0, 2 * batch * n * 16 * d)]
-    for i in range(cfg.half):
-        blocks.append(Block(f"enc{i}", 0.0, per_param, act, act, blk_fl))
-    for i in range(cfg.half):
-        blocks.append(Block(f"dec{i}", 0.0, per_param + 8 * d * d, act, 0,
-                            blk_fl + 2 * batch * n * 2 * d * d))
-    blocks.append(Block("out", 0.0, d * 16 * 2, act, 0, 2 * batch * n * d * 16))
-    total = len(blocks)
-    skips = tuple(SkipEdge(1 + i, total - 2 - i, act) for i in range(cfg.half))
+    pix = cfg.patch ** 2
+    blocks = [Block("embed", 0.0, pix * cfg.in_ch * d * 2, act, 0,
+                    2 * batch * n * pix * cfg.in_ch * d)]
+    for i, (pb, bfl, sb) in enumerate(_hunyuan_rows(cfg, batch)):
+        blocks.append(Block(f"blk{i}", 0.0, pb, act, sb, bfl))
+    blocks.append(Block("out", 0.0, d * pix * cfg.out_ch * 2, act, 0,
+                        2 * batch * n * d * pix * cfg.out_ch))
+    skips = tuple(SkipEdge(1 + i, cfg.n_layers - i, act)
+                  for i in range(cfg.n_skips))
     from repro.core.profiler import analytic_block_costs
     return BlockGraph(analytic_block_costs(blocks, hw), skips)
+
+
+def _hunyuan_rows(cfg: HunyuanDiTConfig, batch: int):
+    """``(param_bytes, flops, skip_bytes)`` of each of the ``n_layers``
+    blocks, in order."""
+    d, n, ff, lt = cfg.d_model, cfg.n_tokens, cfg.d_ff, cfg.text_len
+    hd = d // cfg.n_heads
+    act = batch * n * d * 2
+    flops = 2 * batch * (4 * n * d * d + 2 * n * n * d      # self-attention
+                         + 2 * n * d * d + 2 * lt * cfg.text_dim * d
+                         + 2 * n * lt * d                     # cross
+                         + 2 * n * d * ff)                    # MLP
+    params = (6 * d + 4 * (d * d + d) + 4 * hd + (d * d + d)
+              + 2 * (d * d + d) + 2 * (cfg.text_dim * d + d) + 4 * hd
+              + d * ff + ff + ff * d + d)
+    skip_params = 4 * d + 2 * d * d + d
+    rows = []
+    for i in range(cfg.n_layers):
+        if i < cfg.n_enc:
+            rows.append((params * 2, flops,
+                         act if i < cfg.n_skips else 0))
+        else:
+            rows.append(((params + skip_params) * 2,
+                         flops + 2 * batch * n * 2 * d * d, 0))
+    return rows
 
 
 # --------------------------------------------------------------------------
@@ -712,9 +929,11 @@ def uvit_pipeline_graph(cfg: UViTConfig, batch: int = 1,
                           fwd_times, hw)
 
 
-def _runtime_graph(blocks, skip_edges, fwd_times, hw) -> BlockGraph:
+def _runtime_graph(blocks, skip_edges, fwd_times, hw,
+                   turnaround: int | None = None) -> BlockGraph:
     """Shared tail of the ``*_pipeline_graph`` builders: analytic block
-    costs, optional profiled fwd-time injection, skip-edge attachment."""
+    costs, optional profiled fwd-time injection, skip-edge attachment
+    (and a pinned fold turnaround, see ``BlockGraph.turnaround``)."""
     from repro.core.profiler import analytic_block_costs
     blocks = list(analytic_block_costs(tuple(blocks), hw))
     if fwd_times is not None:
@@ -722,7 +941,7 @@ def _runtime_graph(blocks, skip_edges, fwd_times, hw) -> BlockGraph:
             raise ValueError("fwd_times must have one entry per block")
         blocks = [dataclasses.replace(b, fwd_time=float(t))
                   for b, t in zip(blocks, fwd_times)]
-    return BlockGraph(tuple(blocks), tuple(skip_edges))
+    return BlockGraph(tuple(blocks), tuple(skip_edges), turnaround)
 
 
 def _paired_skips(n_total: int, n_pairs: int, act: int
@@ -736,27 +955,21 @@ def hunyuan_pipeline_graph(cfg: HunyuanDiTConfig, batch: int = 1,
                            hw: Hardware = TPU_V5E) -> BlockGraph:
     """Runtime-aligned Hunyuan-DiT graph for the auto-pipeline compile path.
 
-    Like :func:`uvit_pipeline_graph`: exactly one block per
-    ``enc_blocks``/``dec_blocks`` row (embed/out live in edge params), with
-    the fully-paired skip edges enc i -> dec mirror.  ``fwd_times``
-    (length 2*half) injects profiled per-block times.
+    One block per ``enc_blocks`` / ``dec_blocks`` row, costed at the
+    config's tokens with cross-attention included, the published long
+    skips ``i -> n_layers - 1 - i`` for ``i < n_skips`` (the two middle
+    blocks have no partner), and the fold's turnaround pinned to the stack
+    boundary ``n_enc``: the two stacks have different parameters, so no
+    stage may mix them.  ``fwd_times`` (length ``n_layers``) injects
+    profiled per-block times.
     """
-    d, n, ff, lt = cfg.d_model, cfg.n_tokens, cfg.d_ff, cfg.ctx_len
-    act = batch * n * d * 2
-    blk_fl = 2 * batch * (4 * n * d * d + 2 * n * n * d + 2 * n * d * ff
-                          + 2 * n * d * d + cfg.ctx_dim * 2 * d * lt
-                          + 2 * n * lt * d)
-    per_param = (4 * d * d + 2 * d * ff + 2 * d * d + cfg.ctx_dim * 2 * d
-                 + 6 * d * d) * 2
-    blocks = []
-    for i in range(cfg.half):
-        blocks.append(Block(f"enc{i}", 0.0, per_param, act, act, blk_fl))
-    for i in range(cfg.half):
-        blocks.append(Block(f"dec{i}", 0.0, per_param + 2 * d * d * 2, act,
-                            0, blk_fl + 2 * batch * n * 2 * d * d))
+    act = batch * cfg.n_tokens * cfg.d_model * 2
+    blocks = [Block(f"{'enc' if i < cfg.n_enc else 'dec'}{i}", 0.0, pb, act,
+                    sb, fl)
+              for i, (pb, fl, sb) in enumerate(_hunyuan_rows(cfg, batch))]
     return _runtime_graph(blocks,
-                          _paired_skips(2 * cfg.half, cfg.half, act),
-                          fwd_times, hw)
+                          _paired_skips(cfg.n_layers, cfg.n_skips, act),
+                          fwd_times, hw, turnaround=cfg.n_enc)
 
 
 # --------------------------------------------------------------------------

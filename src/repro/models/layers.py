@@ -16,6 +16,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.runtime import scopes
 
@@ -69,6 +70,34 @@ def apply_rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
     return out.astype(x.dtype)
 
 
+def rope_2d_angles(grid: int, base: int, head_dim: int,
+                   theta: float = 10000.0) -> np.ndarray:
+    """Angles ``(grid * grid, head_dim / 2)`` of 2D RoPE over a row-major
+    ``grid x grid`` token grid, one per interleaved pair of head dims:
+    pairs ``0 .. head_dim/4 - 1`` turn with the token's column, the rest
+    with its row, both at the frequencies of a 1D RoPE of width
+    ``head_dim / 2``; grid index ``i`` sits at position ``i * base / grid``
+    (Hunyuan-DiT's ``get_2d_rotary_pos_embed``).  A host constant."""
+    if head_dim % 4:
+        raise ValueError(f"2D RoPE needs head_dim % 4 == 0, got {head_dim}")
+    half = head_dim // 2
+    freqs = 1.0 / theta ** (np.arange(0, half, 2, dtype=np.float64) / half)
+    pos = np.arange(grid, dtype=np.float64) * (base / grid)
+    col, row = np.tile(pos, grid), np.repeat(pos, grid)
+    return np.concatenate([np.outer(col, freqs), np.outer(row, freqs)],
+                          axis=1).astype(np.float32)
+
+
+def apply_rope_interleaved(x: Array, angles: Array) -> Array:
+    """x: (B, S, H, Dh); angles: (S, Dh/2) for the pairs ``(2i, 2i+1)``."""
+    xf = x.astype(jnp.float32)
+    xe, xo = xf[..., 0::2], xf[..., 1::2]
+    cos = jnp.cos(angles)[:, None, :]
+    sin = jnp.sin(angles)[:, None, :]
+    out = jnp.stack([xe * cos - xo * sin, xe * sin + xo * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 # --------------------------------------------------------------------------
 # Attention (GQA / MQA / MHA, causal + sliding window), dense reference.
 # --------------------------------------------------------------------------
@@ -89,8 +118,17 @@ def _attn_mask(q_len: int, kv_len: int, *, causal: bool, window: int | None,
 @jax.named_scope(scopes.ATTENTION)
 def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
               window: int | None = None, q_offset: Array | int = 0,
-              kv_valid_len: Array | None = None) -> Array:
-    """Grouped-query attention. q: (B,S,Hq,Dh), k/v: (B,T,Hkv,Dh)."""
+              kv_valid_len: Array | None = None,
+              q_block: int | None = None) -> Array:
+    """Grouped-query attention. q: (B,S,Hq,Dh), k/v: (B,T,Hkv,Dh).
+
+    ``q_block`` computes the float32 logits ``q_block`` query rows at a
+    time (``[B, H, q_block, T]`` live at once, each block rematerialized
+    in the backward pass) instead of all ``S`` rows together."""
+    if q_block is not None and q_block < q.shape[1]:
+        return _blocked_attention(q, k, v, q_block, causal=causal,
+                                  window=window, q_offset=q_offset,
+                                  kv_valid_len=kv_valid_len)
     B, S, Hq, Dh = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     groups = Hq // Hkv
@@ -107,6 +145,26 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     return out.reshape(B, S, Hq, v.shape[-1]).astype(q.dtype)
 
 
+def _blocked_attention(q, k, v, q_block: int, *, causal, window, q_offset,
+                       kv_valid_len):
+    """:func:`attention` over query blocks of ``q_block`` rows, one block
+    at a time (``lax.map``), each under ``jax.checkpoint``."""
+    B, S, Hq, Dh = q.shape
+    if S % q_block:
+        raise ValueError(f"q_block {q_block} does not divide {S} queries")
+    n = S // q_block
+
+    @jax.checkpoint
+    def one(args):
+        qb, off = args
+        return attention(qb, k, v, causal=causal, window=window,
+                         q_offset=q_offset + off, kv_valid_len=kv_valid_len)
+
+    qs = jnp.moveaxis(q.reshape(B, n, q_block, Hq, Dh), 1, 0)
+    out = jax.lax.map(one, (qs, jnp.arange(n) * q_block))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, Hq, v.shape[-1])
+
+
 @dataclasses.dataclass(frozen=True)
 class AttnConfig:
     d_model: int
@@ -116,49 +174,112 @@ class AttnConfig:
     rope_theta: float = 10000.0
     window: int | None = None       # sliding-window size (None = full)
     causal: bool = True
-    qk_norm: bool = False           # Qwen3-style per-head q/k RMSNorm
+    qk_norm: bool = False           # per-head q/k norm (kind: qk_norm_kind)
+    qk_norm_kind: str = "rms"       # "rms" (Qwen3, scale only) | "layer"
+    #                                 (LayerNorm with scale and bias)
+    bias: bool = False              # biases on the q/k/v/o projections
+    kv_dim: int | None = None       # width of a cross-attention context
+    #                                 (None: d_model)
+    rope_2d: tuple[int, int] | None = None  # (grid, base): 2D RoPE over a
+    #   grid x grid token grid (rope_2d_angles) in place of 1D RoPE
+    rope_cross_q: bool = False      # cross-attention: 2D RoPE on q alone
+    q_block: int | None = None      # query-blocked logits (attention)
 
 
 def init_attention(key, cfg: AttnConfig, dtype=jnp.float32) -> Params:
     ks = jax.random.split(key, 4)
+    kv_dim = cfg.kv_dim or cfg.d_model
     p = {
         "wq": dense_init(ks[0], cfg.d_model, cfg.n_heads * cfg.head_dim, dtype),
-        "wk": dense_init(ks[1], cfg.d_model, cfg.n_kv_heads * cfg.head_dim, dtype),
-        "wv": dense_init(ks[2], cfg.d_model, cfg.n_kv_heads * cfg.head_dim, dtype),
+        "wk": dense_init(ks[1], kv_dim, cfg.n_kv_heads * cfg.head_dim, dtype),
+        "wv": dense_init(ks[2], kv_dim, cfg.n_kv_heads * cfg.head_dim, dtype),
         "wo": dense_init(ks[3], cfg.n_heads * cfg.head_dim, cfg.d_model, dtype),
     }
-    if cfg.qk_norm:
+    if cfg.bias:
+        for name in ("q", "k", "v"):
+            p["b" + name] = jnp.zeros((p["w" + name].shape[1],), dtype)
+        p["bo"] = jnp.zeros((cfg.d_model,), dtype)
+    if cfg.qk_norm and cfg.qk_norm_kind == "layer":
+        for name in ("q_norm", "k_norm"):
+            p[name] = {"scale": jnp.ones((cfg.head_dim,), dtype),
+                       "bias": jnp.zeros((cfg.head_dim,), dtype)}
+    elif cfg.qk_norm:
         p["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
         p["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
     return p
+
+
+def _proj(x: Array, p: Params, name: str) -> Array:
+    """``x @ w<name>`` (+ ``b<name>`` where the projection has a bias)."""
+    y = x @ p["w" + name]
+    return y + p["b" + name] if "b" + name in p else y
+
+
+def _qk_norm(x: Array, p, cfg: AttnConfig) -> Array:
+    if cfg.qk_norm_kind == "layer":
+        return layer_norm(x, p["scale"], p["bias"], 1e-6)
+    return rms_norm(x, p)
+
+
+def _unrotated_key_bias(p, cfg: AttnConfig, cross: bool):
+    """The key norm's parameters, its bias cut from the gradient where the
+    keys are not rotated: such a bias adds one logit to every key of a
+    query, which the softmax drops, so its true gradient is zero (and not
+    the rounding noise that AdamW would turn into full steps)."""
+    if not cross or cfg.qk_norm_kind != "layer":
+        return p
+    return {**p, "bias": jax.lax.stop_gradient(p["bias"])}
 
 
 def apply_attention(p: Params, x: Array, cfg: AttnConfig, *,
                     positions: Array | None = None,
                     cache: Params | None = None,
                     cross_kv: tuple[Array, Array] | None = None,
+                    context: Array | None = None,
                     ) -> tuple[Array, Params | None]:
     """Self- or cross-attention.  With ``cache`` (decode), x is (B,1,D) and
     the cache is updated in place (functionally).  ``cross_kv`` supplies
     precomputed encoder K/V (whisper-style cross attention; no cache update).
+    ``context`` (B,T,kv_dim) is a cross-attention source that this layer's
+    own ``wk``/``wv`` project (Hunyuan-DiT's text); with ``cfg.rope_2d``
+    and ``cfg.rope_cross_q`` the queries still turn by their positions.
     """
     B, S, _ = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = (x @ p["wq"]).reshape(B, S, H, Dh)
-    if cross_kv is None:
-        k = (x @ p["wk"]).reshape(B, S, Hkv, Dh)
-        v = (x @ p["wv"]).reshape(B, S, Hkv, Dh)
+    cross = cross_kv is not None or context is not None
+    q = _proj(x, p, "q").reshape(B, S, H, Dh)
+    if context is not None:
+        T = context.shape[1]
+        k = _proj(context, p, "k").reshape(B, T, Hkv, Dh)
+        v = _proj(context, p, "v").reshape(B, T, Hkv, Dh)
+    elif cross_kv is None:
+        k = _proj(x, p, "k").reshape(B, S, Hkv, Dh)
+        v = _proj(x, p, "v").reshape(B, S, Hkv, Dh)
     else:
         k, v = cross_kv
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        if cross_kv is None:
-            k = rms_norm(k, p["k_norm"])
-    if positions is None:
-        positions = jnp.arange(S)[None, :]
-    if cfg.rope_theta > 0 and cross_kv is None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope_2d is not None:
+        # the per-head norms and the rotation belong to the attention
+        angles = rope_2d_angles(*cfg.rope_2d, Dh, cfg.rope_theta)
+        with jax.named_scope(scopes.ATTENTION):
+            if cfg.qk_norm:
+                q = _qk_norm(q, p["q_norm"], cfg)
+                if cross_kv is None:
+                    k = _qk_norm(k, _unrotated_key_bias(p["k_norm"], cfg,
+                                                        cross), cfg)
+            if not cross or cfg.rope_cross_q:
+                q = apply_rope_interleaved(q, angles)
+            if not cross:
+                k = apply_rope_interleaved(k, angles)
+    else:
+        if cfg.qk_norm:
+            q = _qk_norm(q, p["q_norm"], cfg)
+            if cross_kv is None:
+                k = _qk_norm(k, p["k_norm"], cfg)
+        if positions is None:
+            positions = jnp.arange(S)[None, :]
+        if cfg.rope_theta > 0 and not cross:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
 
     new_cache = None
     if cache is not None and cross_kv is None:
@@ -169,9 +290,9 @@ def apply_attention(p: Params, x: Array, cfg: AttnConfig, *,
         out = attention(q, k_all, v_all, causal=cfg.causal, window=cfg.window,
                         q_offset=pos, kv_valid_len=pos + S)
     else:
-        out = attention(q, k, v, causal=cfg.causal and cross_kv is None,
-                        window=cfg.window)
-    out = out.reshape(B, S, H * Dh) @ p["wo"]
+        out = attention(q, k, v, causal=cfg.causal and not cross,
+                        window=cfg.window, q_block=cfg.q_block)
+    out = _proj(out.reshape(B, S, H * Dh), p, "o")
     return out, new_cache
 
 

@@ -129,29 +129,36 @@ class LMPipelineAdapter:
 
 
 # ===========================================================================
-# UViT / Hunyuan-DiT (wave with real skip tensors)
+# UViT (wave with real skip tensors)
 # ===========================================================================
 
 @dataclasses.dataclass(frozen=True)
 class DiffusionPipelineAdapter:
-    """Folded wave pipeline for UViT / Hunyuan-DiT.
+    """Folded wave pipeline for UViT (hand-written executors).
 
     Microbatch inputs (all stacked [M, b, ...]):
-      mb:  {"xt", "noise", plus model conditioning ("labels" | nothing)}
-      aux: {"t"} for UViT (time token built in embed); Hunyuan additionally
-           carries {"temb", "ctx"} to every stage.
+      mb:  {"xt", "noise", "labels"}
+      aux: {"t"} (the time token is built in embed).
+
+    Hunyuan-DiT's two stacks differ in depth (21 + 19 blocks), which the
+    even S = 2D split here cannot lay out: it runs through
+    ``auto_pipeline`` with :func:`hunyuan_model_fns`.
     """
 
-    cfg: Any                     # UViTConfig | HunyuanDiTConfig
+    cfg: Any                     # UViTConfig
     pcfg: PipelineConfig | None  # None: callbacks-only (diffusion_model_fns
-    kind: str = "uvit"           # borrows embed/loss/_blk_kwargs; build/
-                                 # split_params need a real PipelineConfig).
-                                 # kind: "uvit" | "hunyuan"
+    kind: str = "uvit"           # borrows embed/loss; build/split_params
+                                 # need a real PipelineConfig)
+
+    def __post_init__(self):
+        if self.kind != "uvit":
+            raise ValueError(
+                f"DiffusionPipelineAdapter runs UViT only, not {self.kind!r}"
+                "; Hunyuan-DiT goes through auto_pipeline with "
+                "hunyuan_model_fns")
 
     def init_pipeline_params(self, key) -> tuple:
-        init = (diff_mod.init_uvit if self.kind == "uvit"
-                else diff_mod.init_hunyuan)
-        return self.split_params(init(key, self.cfg))
+        return self.split_params(diff_mod.init_uvit(key, self.cfg))
 
     def split_params(self, params: Pytree) -> tuple:
         D = self.pcfg.num_devices
@@ -168,46 +175,26 @@ class DiffusionPipelineAdapter:
                 "dec_blocks": _ungroup(stacks[1], reverse=True)}
 
     def embed_fn(self, edge_p, mb, aux):
-        if self.kind == "uvit":
-            return diff_mod.uvit_embed(edge_p, mb["xt"], aux["t"], mb, self.cfg)
-        tok = diff_mod._patchify(mb["xt"].astype(self.cfg.dtype),
-                                 self.cfg.patch) @ edge_p["patch_embed"].astype(self.cfg.dtype)
-        return tok + edge_p["pos_embed"].astype(self.cfg.dtype)[None]
-
-    def _blk_kwargs(self, aux):
-        if self.kind == "uvit":
-            return {}
-        return {"ctx": aux["ctx"], "temb": aux["temb"]}
+        return diff_mod.uvit_embed(edge_p, mb["xt"], aux["t"], mb, self.cfg)
 
     def enc_stage_fn(self, stage_p, x, aux):
-        kw = self._blk_kwargs(aux)
-
         def body(x, bp):
-            x = diff_mod._apply_vit_block(bp, x, self.cfg, **kw)
+            x = diff_mod._apply_vit_block(bp, x, self.cfg)
             return x, x
 
         x, skips = jax.lax.scan(body, x, stage_p)
         return x, skips
 
     def dec_stage_fn(self, stage_p, x, skips, aux):
-        kw = self._blk_kwargs(aux)
-
         def body(x, inp):
             bp, skip = inp
-            return diff_mod._apply_vit_block(bp, x, self.cfg, skip=skip, **kw), None
+            return diff_mod._apply_vit_block(bp, x, self.cfg, skip=skip), None
 
         x, _ = jax.lax.scan(body, x, (stage_p, skips[::-1]))
         return x
 
     def loss_fn(self, edge_p, x, mb, aux):
-        if self.kind == "uvit":
-            pred = diff_mod.uvit_output(edge_p, x, self.cfg)
-        else:
-            from repro.models.layers import rms_norm
-            h = rms_norm(x, edge_p["out_norm"], self.cfg.norm_eps)
-            pix = h @ edge_p["out_proj"].astype(h.dtype)
-            pred = diff_mod._unpatchify(pix, self.cfg.patch,
-                                        self.cfg.img_size, self.cfg.in_ch)
+        pred = diff_mod.uvit_output(edge_p, x, self.cfg)
         return jnp.mean(jnp.square(pred.astype(jnp.float32)
                                    - mb["noise"].astype(jnp.float32)))
 
@@ -246,16 +233,11 @@ class DiffusionPipelineAdapter:
 
 
 def make_diffusion_microbatches(batch: dict, rng, M: int, cfg,
-                                kind: str = "uvit",
-                                params: Pytree | None = None
-                                ) -> tuple[dict, dict]:
+                                kind: str = "uvit") -> tuple[dict, dict]:
     """Sample DDPM (t, noise) per microbatch and reshape [B,...] ->
-    [M, B/M, ...] stacked microbatches + aux conditioning.
-
-    For Hunyuan the per-stage adaLN conditioning ``temb`` is computed once
-    here from the (replicated) ``time_mlp`` params and broadcast down the
-    pipeline as aux; its gradient psums across stages via the shard_map
-    transpose."""
+    [M, B/M, ...] stacked microbatches + aux conditioning ``{"t"}``.
+    ``kind="uvit"`` (UViT, SkipViT) carries the class labels in ``mb``;
+    ``"hunyuan"`` leaves the conditioning to :func:`hunyuan_microbatches`."""
     B = batch["latents"].shape[0]
     b = B // M
     rt, rn = jax.random.split(rng)
@@ -269,14 +251,35 @@ def make_diffusion_microbatches(batch: dict, rng, M: int, cfg,
     aux = {"t": split(t)}
     if kind == "uvit":
         mb["labels"] = split(batch["labels"])
-    else:
-        from repro.models.layers import apply_gelu_mlp
-        temb = apply_gelu_mlp(
-            params["time_mlp"],
-            diff_mod.timestep_embedding(t, cfg.d_model).astype(cfg.dtype))
-        aux["ctx"] = split(batch["text_embeds"].astype(cfg.dtype))
-        aux["temb"] = split(temb)
     return mb, aux
+
+
+def hunyuan_microbatches(edge: Pytree, batch: dict, rng, M: int,
+                         cfg) -> tuple[dict, dict]:
+    """Hunyuan-DiT's microbatches: the noised latents and noise in ``mb``,
+    and in ``aux`` the conditioning vector ``c`` and the text tokens,
+    computed here once a step from the replicated edge parameters (under
+    the ``pulse.embed`` scope).  Every stage reads them and the head reads
+    ``c``; their cotangents are summed over the pipeline axis by the
+    shard_map transpose, once a step."""
+    from repro.runtime import scopes
+
+    mb, aux = make_diffusion_microbatches(batch, rng, M, cfg, "hunyuan")
+    with jax.named_scope(scopes.EMBED):
+        c, text = diff_mod.hunyuan_condition(edge, aux["t"].reshape(-1),
+                                             batch, cfg)
+    split = lambda x: x.reshape(M, -1, *x.shape[1:])
+    return mb, {"c": split(c), "text": split(text)}
+
+
+def pipeline_microbatches(cfg) -> Callable:
+    """``(edge, batch, rng, M) -> (mbs, aux)`` for the config's model kind:
+    Hunyuan-DiT's conditioning, or UViT's (and SkipViT's) labels."""
+    if isinstance(cfg, diff_mod.HunyuanDiTConfig):
+        return lambda edge, batch, rng, M: hunyuan_microbatches(
+            edge, batch, rng, M, cfg)
+    return lambda edge, batch, rng, M: make_diffusion_microbatches(
+        batch, rng, M, cfg, "uvit")
 
 
 # ===========================================================================
@@ -319,7 +322,8 @@ def lm_model_fns(cfg: LMConfig):
 
 
 def diffusion_model_fns(cfg: Any, kind: str = "uvit"):
-    """UViT / Hunyuan-DiT as block-level compile-path callables.
+    """UViT as block-level compile-path callables (Hunyuan-DiT:
+    :func:`hunyuan_model_fns`).
 
     Pairs with :func:`repro.models.diffusion.uvit_pipeline_graph`: every
     encoder block emits its output as a skip; the mirror decoder block
@@ -330,27 +334,70 @@ def diffusion_model_fns(cfg: Any, kind: str = "uvit"):
     ad = DiffusionPipelineAdapter(cfg, None, kind)   # callbacks only
 
     def enc_block_fn(bp, x, aux):
-        y = diff_mod._apply_vit_block(bp, x, cfg, **ad._blk_kwargs(aux))
+        y = diff_mod._apply_vit_block(bp, x, cfg)
         return y, y
 
     def dec_block_fn(bp, x, skip, aux):
-        return diff_mod._apply_vit_block(bp, x, cfg, skip=skip,
-                                         **ad._blk_kwargs(aux))
+        return diff_mod._apply_vit_block(bp, x, cfg, skip=skip)
 
-    def split_blocks(params):
-        edge = {k: v for k, v in params.items()
-                if k not in ("enc_blocks", "dec_blocks")}
-        return (params["enc_blocks"], params["dec_blocks"]), edge
-
-    def merge_blocks(stacks, edge):
-        return {**edge, "enc_blocks": stacks[0], "dec_blocks": stacks[1]}
-
-    init = diff_mod.init_uvit if kind == "uvit" else diff_mod.init_hunyuan
     return PipelineModelFns(
-        init_fn=lambda key: init(key, cfg),
+        init_fn=lambda key: diff_mod.init_uvit(key, cfg),
         embed_fn=ad.embed_fn, loss_fn=ad.loss_fn,
         enc_block_fn=enc_block_fn, dec_block_fn=dec_block_fn,
-        split_blocks=split_blocks, merge_blocks=merge_blocks,
+        split_blocks=_split_two_stacks, merge_blocks=_merge_two_stacks,
+        num_param_stacks=2)
+
+
+def _split_two_stacks(params):
+    edge = {k: v for k, v in params.items()
+            if k not in ("enc_blocks", "dec_blocks")}
+    return (params["enc_blocks"], params["dec_blocks"]), edge
+
+
+def _merge_two_stacks(stacks, edge):
+    return {**edge, "enc_blocks": stacks[0], "dec_blocks": stacks[1]}
+
+
+def hunyuan_model_fns(cfg):
+    """Hunyuan-DiT as block-level compile-path callables.
+
+    Pairs with :func:`repro.models.diffusion.hunyuan_pipeline_graph`.  The
+    two stacks are the blocks without skip parameters (the first
+    ``n_enc``; every one emits its output to the stash, the middle two to
+    no reader) and those with (each consumes the skip the layout pairs it
+    with).  ``aux`` carries ``c`` and the text tokens per microbatch
+    (:func:`hunyuan_microbatches`); the head reads ``c`` too.
+
+    Each block keeps only the outputs of its matrix products (no batch
+    dims) for its backward and recomputes the rest: the stage's block scan
+    otherwise holds every block's norms, GELU and attention intermediates
+    at once.
+    """
+    from repro.runtime.compile import PipelineModelFns
+
+    block = jax.checkpoint(
+        diff_mod.apply_dit_block, static_argnums=(2,),
+        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+    def embed_fn(edge_p, mb, aux):
+        return diff_mod.hunyuan_embed(edge_p, mb["xt"], cfg)
+
+    def enc_block_fn(bp, x, aux):
+        y = block(bp, x, cfg, aux["c"], aux["text"])
+        return y, y
+
+    def dec_block_fn(bp, x, skip, aux):
+        return block(bp, x, cfg, aux["c"], aux["text"], skip=skip)
+
+    def loss_fn(edge_p, x, mb, aux):
+        pred = diff_mod.hunyuan_output(edge_p, x, aux["c"], cfg)
+        return diff_mod.hunyuan_eps_loss(pred, mb["noise"], cfg)
+
+    return PipelineModelFns(
+        init_fn=lambda key: diff_mod.init_hunyuan(key, cfg),
+        embed_fn=embed_fn, loss_fn=loss_fn,
+        enc_block_fn=enc_block_fn, dec_block_fn=dec_block_fn,
+        split_blocks=_split_two_stacks, merge_blocks=_merge_two_stacks,
         num_param_stacks=2)
 
 

@@ -316,8 +316,9 @@ class StageLayout:
                 raise ValueError(
                     f"partition turnaround cut at block {mid} but the "
                     f"model's encoder stack has {enc_rows} rows; two-stack "
-                    "models need the mid cut on the stack boundary (add "
-                    "skip edges pinning it, or use a homogeneous stack)")
+                    "models need the mid cut on the stack boundary (pin it "
+                    "with BlockGraph.turnaround, or use a homogeneous "
+                    "stack)")
         return (self._stack(enc_b, self.enc_ranges(), self.enc_pad),
                 self._stack(dec_b, self.dec_ranges(), self.dec_pad))
 
@@ -626,6 +627,20 @@ class CompiledPipeline:
                 return wrap(edge, mbs)(stacks[0], edge, mbs)
         return loss
 
+    def _fold_lines(self) -> list[str]:
+        """The fold's turnaround block (pinned by the graph or chosen by
+        the partitioner) and the blocks no skip edge touches."""
+        part, graph = self.partition, self.graph
+        if not part.folded:
+            return []
+        mid = part.cuts[part.num_stages // 2]
+        how = ("pinned to the stack boundary" if graph.turnaround is not None
+               else "chosen by the partitioner")
+        ends = {b for e in graph.skips for b in (e.src, e.dst)}
+        free = [b for b in range(graph.n) if b not in ends]
+        return [f"  fold: turnaround at block {mid} ({how}); blocks without "
+                f"a skip partner: {', '.join(map(str, free)) or 'none'}"]
+
     def describe(self) -> str:
         part, sched = self.partition, self.schedule
         V = self.layout.V
@@ -642,6 +657,7 @@ class CompiledPipeline:
              + ("" if part.mirror_symmetric() else " (asymmetric fold)")
              if part.folded else
              f"  layout: stage counts={self.layout.enc_counts}"),
+            *self._fold_lines(),
             f"  schedule: makespan={sched.makespan} slots, "
             f"bubble={sched.bubble_ratio():.2f}",
             f"  executor: {self.executor}",

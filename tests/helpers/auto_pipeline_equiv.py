@@ -32,9 +32,12 @@ Configs (pass names as argv to run a subset; default: all):
                  independent enc/dec counts + graph-derived skip pairing
   wave-sparse    SkipViT with a sparse skip set (one pair dropped) ->
                  asymmetric fold with skip-less decoder rows
-  wave-hunyuan   Hunyuan-DiT small config through the compile path
-                 (adaLN + cross-attn blocks; time-MLP grads flow through
-                 the aux conditioning closure)
+  wave-hunyuan   Hunyuan-DiT at tiny widths, depth 8 (5 + 3 blocks, two
+                 unpaired), P=2: the fold pinned to the stack boundary;
+                 the conditioning's grads flow back through aux; vs
+                 hunyuan_loss and bench/reference/hydit.py
+  wave-hunyuan-dp2
+                 the same on a (2, 2) mesh: two data-parallel replicas
   linear-zero2 / wave-zero1 / wave-zero2
                  hybrid ZeRO x pipeline (dp=2, P=2): ZeRO-sharded
                  param/optimizer stacks vs the unsharded reference
@@ -51,14 +54,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.diffusion import (HunyuanDiTConfig, SkipViTConfig,
-                                    UViTConfig, hunyuan_apply,
+from repro.models.diffusion import (SkipViTConfig, UViTConfig,
                                     hunyuan_pipeline_graph, skipvit_apply,
                                     skipvit_pipeline_graph,
                                     uvit_apply, uvit_pipeline_graph)
 from repro.models.layers import AttnConfig
 from repro.models.lm import LMConfig, lm_loss, lm_pipeline_graph
-from repro.runtime.adapters import (diffusion_model_fns, lm_model_fns,
+from repro.runtime.adapters import (diffusion_model_fns,
+                                    hunyuan_model_fns, lm_model_fns,
                                     make_diffusion_microbatches,
                                     skipvit_model_fns)
 from repro.runtime.compile import auto_pipeline
@@ -352,83 +355,79 @@ def _run_skipvit(name, cfg, fwd_times, *, pipeline_devices=2,
         _diff_overlap(cp, mesh, state, (mb, aux), name)
 
 
-def _run_hunyuan(name, *, pipeline_devices=2, microbatches=4):
-    """Hunyuan-DiT small config through auto_pipeline vs the single-device
-    model.
+def _run_hunyuan(name, *, pipeline_devices=2, microbatches=4, dp_size=1):
+    """Hunyuan-DiT (its block, conditioning and final layer at tiny widths,
+    depth 8 shaped like DiT-g/2's 40: 5 blocks without skip parameters and
+    3 with, blocks 3 and 4 unpaired) through auto_pipeline on a P=2 mesh
+    vs the single-device ``hunyuan_loss`` and vs the benchmark's plain
+    float32 reference (``bench/reference/hydit.py``, which imports nothing
+    of the program): loss and every leaf's gradient at rtol 1e-4.  The
+    conditioning runs once outside the executor; its cotangent comes back
+    through the shard_map transpose, summed over the pipeline axis and,
+    with ``dp_size`` replicas, over the data axis too."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    from bench.reference import hydit as reference
+    from repro.configs.smoke import hunyuan_tiny, hunyuan_tiny_batch
+    from repro.models.diffusion import hunyuan_loss
+    from repro.runtime.adapters import hunyuan_microbatches
 
-    Loss is checked against the *true* ``hunyuan_apply`` (which recomputes
-    the adaLN ``temb`` from the time-MLP params — identical values since
-    the aux conditioning was produced from the same params).  Gradients are
-    checked against a block-loop reference that, like the executor, takes
-    (temb, ctx) as microbatch data — both sides differentiate the same
-    function of the block/edge parameters."""
-    from repro.models import diffusion as dm
-    from repro.models.layers import rms_norm
-
-    cfg = HunyuanDiTConfig("t", img_size=8, in_ch=4, patch=2, d_model=32,
-                           n_layers=8, n_heads=4, d_ff=64, ctx_dim=16,
-                           ctx_len=4)
+    # the published path's memory setting: query blocks
+    cfg = hunyuan_tiny("t", n_layers=8, q_block=8)
     graph = hunyuan_pipeline_graph(cfg)
-    cp = auto_pipeline(graph, diffusion_model_fns(cfg, "hunyuan"),
-                       pipeline_devices, pipeline_devices=pipeline_devices,
-                       microbatches=microbatches, lam=0.0, dp_size=2,
+    cp = auto_pipeline(graph, hunyuan_model_fns(cfg), pipeline_devices,
+                       pipeline_devices=pipeline_devices,
+                       microbatches=microbatches, lam=0.0, dp_size=dp_size,
                        wire_dtype="float32")
-    assert cp.folded and cp.partition.num_stages == 2 * pipeline_devices
+    S = cp.partition.num_stages
+    assert cp.folded and S == 2 * pipeline_devices
+    assert cp.partition.cuts[S // 2] == cfg.n_enc == 5, cp.partition.cuts
+    assert "turnaround at block 5 (pinned" in cp.describe()
+    assert "skip partner: 3, 4" in cp.describe()
     _check_tables_match_grid(cp, name)
 
-    mesh = jax.make_mesh((2, pipeline_devices), ("data", "model"))
+    mesh = jax.make_mesh((dp_size, pipeline_devices), ("data", "model"))
     params = cp.model_fns.init_fn(KEY)
     state = cp.split_params(params)
-    M = microbatches
-    B = 2 * M
-    batch = {"latents": jax.random.normal(KEY, (B, 8, 8, 4)),
-             "text_embeds": jax.random.normal(KEY, (B, 4, 16))}
-    mb, aux = make_diffusion_microbatches(batch, KEY, M, cfg, "hunyuan",
-                                          params=params)
-    loss = cp.bind(mesh)
-    lp = jax.jit(loss)(state, mb, aux)
+    M, B = microbatches, 2 * microbatches   # rows of a microbatch: 2
+    batch = hunyuan_tiny_batch(jax.random.PRNGKey(5), cfg, B)
+    rng = jax.random.PRNGKey(7)
+    loss_of_mb = cp.bind(mesh)
 
-    def ref_true(params):
-        """End-to-end model: temb recomputed from params inside."""
-        losses = []
-        ctx_mb = batch["text_embeds"].reshape(M, B // M, 4, 16)
-        for m in range(M):
-            pred = hunyuan_apply(params, mb["xt"][m], aux["t"][m],
-                                 {"text_embeds": ctx_mb[m]}, cfg)
-            losses.append(jnp.mean(jnp.square(pred - mb["noise"][m])))
-        return jnp.mean(jnp.asarray(losses))
+    def loss(state):
+        mb, aux = hunyuan_microbatches(state[1], batch, rng, M, cfg)
+        return loss_of_mb(state, mb, aux)
 
-    def ref_aux(params):
-        """Same dataflow as the executor: (temb, ctx) enter as data."""
-        losses = []
-        for m in range(M):
-            x = (dm._patchify(mb["xt"][m], cfg.patch)
-                 @ params["patch_embed"] + params["pos_embed"][None])
-            kw = {"ctx": aux["ctx"][m], "temb": aux["temb"][m]}
-            skips = []
-            for r in range(cfg.half):
-                bp = jax.tree.map(lambda a: a[r], params["enc_blocks"])
-                x = dm._apply_vit_block(bp, x, cfg, **kw)
-                skips.append(x)
-            for r in range(cfg.half):
-                bp = jax.tree.map(lambda a: a[r], params["dec_blocks"])
-                x = dm._apply_vit_block(bp, x, cfg,
-                                        skip=skips[cfg.half - 1 - r], **kw)
-            h = rms_norm(x, params["out_norm"], cfg.norm_eps)
-            pred = dm._unpatchify(h @ params["out_proj"], cfg.patch,
-                                  cfg.img_size, cfg.in_ch)
-            losses.append(jnp.mean(jnp.square(pred - mb["noise"][m])))
-        return jnp.mean(jnp.asarray(losses))
+    lp, gp = jax.jit(jax.value_and_grad(loss))(state)
+    gp = cp.merge_params(gp[0], gp[1])
+    with jax.default_matmul_precision("highest"):
+        ls, gs = jax.jit(jax.value_and_grad(
+            lambda p: hunyuan_loss(p, batch, rng, cfg)))(params)
+    np.testing.assert_allclose(float(lp), float(ls), rtol=RTOL)
+    _check_grads(gp, gs, name + " vs hunyuan_loss")
 
-    lt = jax.jit(ref_true)(params)
-    la = jax.jit(ref_aux)(params)
-    np.testing.assert_allclose(float(lp), float(lt), rtol=RTOL)
-    np.testing.assert_allclose(float(lp), float(la), rtol=RTOL)
-    gp = jax.jit(jax.grad(loss))(state, mb, aux)
-    _check_grads(cp.merge_params(gp[0], gp[1]),
-                 jax.jit(jax.grad(ref_aux))(params), name)
-    print(f"{name}: counts={cp.layout.counts} loss={float(lp):.6f} "
-          f"== hunyuan_apply {float(lt):.6f}; grads OK")
+    c = {k: getattr(cfg, k) for k in (
+        "in_ch", "out_ch", "patch", "d_model", "n_layers", "n_heads",
+        "d_ff", "text_dim", "clip_len", "t5_dim", "t5_len", "t5_hidden",
+        "pool_heads", "pool_dim", "freq_dim", "rope_theta", "rope_base",
+        "norm_eps")}
+    c.update(head_dim=cfg.d_model // cfg.n_heads, param_dtype="float32",
+             dtype="float32")
+    assert all(bool(jnp.all(a == b)) for a, b in zip(
+        jax.tree.leaves(reference.init(KEY, c)), jax.tree.leaves(params)))
+    # the reference runs a sample per device over as many devices as the
+    # pipeline's mesh (two rounds of four, or four of two)
+    ref = reference.Reference(c, cfg.img_size,
+                              jax.devices()[:pipeline_devices * dp_size])
+    lr, gr = ref.loss_and_grads(ref.init(KEY), batch, rng)
+    np.testing.assert_allclose(float(lp), lr, rtol=RTOL)
+    np.testing.assert_allclose(float(ls), lr, rtol=RTOL)
+    gr = reference._model_space(gr)
+    _check_grads(gp, gr, name + " vs reference")
+    _check_grads(gs, gr, "hunyuan_loss vs reference")
+    print(f"{name}: cuts={cp.partition.cuts} enc={cp.layout.enc_counts} "
+          f"dec={cp.layout.dec_counts} loss={float(lp):.6f} == "
+          f"hunyuan_loss {float(ls):.6f} == reference {lr:.6f}; grads OK")
 
 
 CONFIGS = {
@@ -468,9 +467,12 @@ CONFIGS = {
         SkipViTConfig("t", n_enc=3, n_mid=2, n_dec=3,
                       skip_pairs=((0, 7), (2, 5))),
         [1, 1, 4, 0.5, 0.5, 0.5, 1, 1], compare_closed=False),
-    # Hunyuan-DiT model_fns coverage (ROADMAP item): adaLN + cross-attn
-    # blocks through the full compile path vs the single-device reference
+    # Hunyuan-DiT: its block, conditioning and final layer through the
+    # full compile path vs the single-device model and the plain reference
     "wave-hunyuan": lambda: _run_hunyuan("wave-hunyuan"),
+    # the same over two data-parallel replicas of the P=2 pipeline: each
+    # replica's conditioning cotangent is summed over both mesh axes
+    "wave-hunyuan-dp2": lambda: _run_hunyuan("wave-hunyuan-dp2", dp_size=2),
     # Hybrid ZeRO x pipeline (dp=2, P=2, fp32 wire): the executor runs DP
     # replicas of the pipeline with ZeRO-sharded state, and must still
     # match the unsharded single-replica reference at rtol 1e-4.

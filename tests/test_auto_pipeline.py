@@ -280,15 +280,20 @@ def test_layout_rejects_malformed_folds():
 
 
 def test_hunyuan_config_plans_through_auto_pipeline():
-    """configs/hunyuan_dit wires the paper's own model through the compile
-    path: the full-size config plans, schedules and lays out (planning is
-    host-side; the numerical smoke test runs in the subprocess harness)."""
+    """configs/hunyuan_dit wires DiT-g/2 at its published widths through
+    the compile path: the 40-block graph plans, schedules and lays out
+    with the fold turning on the stack boundary (block 21) and blocks 19
+    and 20 without a skip partner (planning is host-side; the numerical
+    test at tiny widths runs in the subprocess harness)."""
     from repro.configs import hunyuan_dit
     cp = hunyuan_dit.auto_plan(8, pipeline_devices=8, microbatches=8)
     assert cp.folded and cp.partition.num_stages == 16
+    assert cp.partition.cuts[8] == hunyuan_dit.CFG.n_enc == 21
     assert cp.partition.validate_collocation(cp.graph)
     assert (sum(c for cs in cp.layout.enc_counts for c in cs)
-            + sum(c for cs in cp.layout.dec_counts for c in cs) == 32)
+            + sum(c for cs in cp.layout.dec_counts for c in cs) == 40)
+    assert "turnaround at block 21 (pinned" in cp.describe()
+    assert "skip partner: 19, 20" in cp.describe()
     assert not validate_schedule(cp.schedule, cp.partition.device_of_stage,
                                  collocated=cp.partition.collocated_pairs())
 
@@ -582,12 +587,21 @@ def test_auto_pipeline_equivalence_interleaved_ilp():
     _run_equiv("wave-interleaved-ilp", "linear-interleaved")
 
 
-@pytest.mark.slow
 def test_auto_pipeline_equivalence_hunyuan():
-    """Hunyuan-DiT model_fns coverage (ROADMAP item): a small Hunyuan
-    config through the full compile path matches hunyuan_apply (loss) and
-    the aux-as-data block-loop reference (grads)."""
+    """Hunyuan-DiT at tiny widths, depth 8 shaped like DiT-g/2 (two
+    unpaired middle blocks), through the full compile path at P=2, M=4:
+    loss and every leaf's gradient match hunyuan_loss and the plain
+    float32 reference of bench/reference/hydit.py at rtol 1e-4."""
     _run_equiv("wave-hunyuan")
+
+
+def test_auto_pipeline_equivalence_hunyuan_dp2():
+    """The same Hunyuan-DiT over two data-parallel replicas of the P=2
+    pipeline (a (2, 2) mesh): the conditioning computed once outside the
+    executor gets its cotangent summed over both mesh axes, and loss and
+    every leaf's gradient still match hunyuan_loss and the plain
+    reference at rtol 1e-4."""
+    _run_equiv("wave-hunyuan-dp2")
 
 
 @pytest.mark.slow

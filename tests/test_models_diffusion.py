@@ -5,7 +5,7 @@ import jax.numpy as jnp
 
 from repro.models.diffusion import (UViTConfig, init_uvit, uvit_loss,
                                     uvit_apply, uvit_block_graph,
-                                    HunyuanDiTConfig, init_hunyuan,
+                                    init_hunyuan,
                                     hunyuan_loss, hunyuan_block_graph,
                                     UNetConfig, init_unet, unet_loss,
                                     unet_block_graph, cosine_alpha_bar)
@@ -61,23 +61,27 @@ def test_skip_kernel_differential_uvit():
 
 
 def test_skip_kernel_differential_hunyuan():
-    """Same flag on the Hunyuan-DiT decoder blocks (adaLN + cross-attn
-    around the fused skip-in)."""
+    """Same flag on the Hunyuan-DiT blocks that take a skip: the LayerNorm
+    over ``[x | skip]`` is taken over the two halves apart and the fused
+    kernel does the projection; forward and grads match the concat."""
     import dataclasses
     import numpy as np
+    from repro.configs.smoke import hunyuan_tiny, hunyuan_tiny_batch
     from repro.models.diffusion import hunyuan_apply
-    cfg = HunyuanDiTConfig("t", img_size=8, in_ch=4, patch=2, d_model=32,
-                           n_layers=4, n_heads=4, d_ff=64, ctx_dim=16,
-                           ctx_len=7)
+    cfg = hunyuan_tiny("t", n_layers=4)
     cfg_k = dataclasses.replace(cfg, use_skip_kernel=True)
     p = init_hunyuan(KEY, cfg)
-    batch = {"latents": jax.random.normal(KEY, (2, 8, 8, 4)),
-             "text_embeds": jax.random.normal(KEY, (2, 7, 16))}
+    batch = hunyuan_tiny_batch(KEY, cfg)
     t = jnp.array([0.1, 0.9])
     ref = hunyuan_apply(p, batch["latents"], t, batch, cfg)
     ker = hunyuan_apply(p, batch["latents"], t, batch, cfg_k)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
+    gr = jax.grad(lambda p: hunyuan_loss(p, batch, KEY, cfg))(p)
+    gk = jax.grad(lambda p: hunyuan_loss(p, batch, KEY, cfg_k))(p)
+    for a, b in zip(jax.tree.leaves(gk), jax.tree.leaves(gr)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
 
 
 def test_uvit_graph_nested_symmetric():
@@ -93,15 +97,18 @@ def test_uvit_graph_nested_symmetric():
 
 @pytest.mark.slow
 def test_hunyuan_loss():
-    cfg = HunyuanDiTConfig("t", img_size=8, in_ch=4, patch=2, d_model=32,
-                           n_layers=4, n_heads=4, d_ff=64, ctx_dim=16,
-                           ctx_len=7)
+    from repro.configs.smoke import hunyuan_tiny, hunyuan_tiny_batch
+    from repro.models.diffusion import hunyuan_apply
+    cfg = hunyuan_tiny("t", n_layers=8)
     p = init_hunyuan(KEY, cfg)
-    batch = {"latents": jax.random.normal(KEY, (2, 8, 8, 4)),
-             "text_embeds": jax.random.normal(KEY, (2, 7, 16))}
+    batch = hunyuan_tiny_batch(KEY, cfg)
+    pred = hunyuan_apply(p, batch["latents"], jnp.array([0.1, 0.9]), batch,
+                         cfg)
+    assert pred.shape == (2, 8, 8, cfg.out_ch)
     loss = hunyuan_loss(p, batch, KEY, cfg)
     assert jnp.isfinite(loss)
-    assert hunyuan_block_graph(cfg, 2).is_nested()
+    g = hunyuan_block_graph(cfg, 2)
+    assert g.is_nested() and len(g.skips) == cfg.n_skips == 3
 
 
 @pytest.mark.slow

@@ -38,10 +38,10 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-@pytest.mark.parametrize("width", [2560, 2048], ids=["uvit_h", "hunyuan_dit"])
+@pytest.mark.parametrize("width", [2560, 1408], ids=["uvit_h", "hunyuan_dit"])
 def test_skip_concat_matmul_compiles_for_v5e(one_chip, width):
-    """Decoder skip-in at UViT-H (D=N=2560) and Hunyuan-DiT (D=N=2048)
-    width, bf16, 2048 rows."""
+    """Decoder skip-in at UViT-H (D=N=2560) and Hunyuan-DiT DiT-g/2
+    (D=N=1408) width, bf16, 2048 rows."""
     h = jax.ShapeDtypeStruct((2048, width), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((2 * width, width), jnp.bfloat16,
                              sharding=one_chip)
@@ -51,8 +51,8 @@ def test_skip_concat_matmul_compiles_for_v5e(one_chip, width):
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
-    """Forward at Hunyuan-DiT's 1,024 latent tokens (64x64 latent, patch
-    2), 16 heads of head_dim 128, bf16, non-causal."""
+    """Forward at 1,024 latent tokens (64x64 latent, patch 2), 16 heads
+    of head_dim 128, bf16, non-causal."""
     q = jax.ShapeDtypeStruct((16, 1024, 128), jnp.bfloat16,
                              sharding=one_chip)
     text = _compiled_text(
