@@ -32,7 +32,7 @@ PLAN_SCHEMA = "repro.saved-plan/v1"
 WIRE_DTYPES = ("bfloat16", "float32")
 
 _TABLE_FIELDS = (
-    "D", "M", "V", "rings", "forward_steps", "sel", "slot", "mb",
+    "D", "M", "V", "rings", "sel", "slot", "mb",
     "down_mb", "down_valid", "up_mb", "up_valid", "loss", "embed",
     "turn_rd", "turn_wr", "down_send", "up_send", "down_slot", "up_slot",
     "rx_slot", "turn_wr_slot", "turn_rd_slot", "skip_wr", "skip_wr_slot",
@@ -277,8 +277,7 @@ def export_plan(tabs, path, *, skip_consumers=None, overlap: bool = True,
         val = getattr(tabs, field)
         doc["tables"][field] = (np.asarray(val).tolist()
                                 if isinstance(val, np.ndarray)
-                                else (list(val) if isinstance(val, tuple)
-                                      else int(val)))
+                                else int(val))
     with open(path, "w") as fh:
         json.dump(doc, fh, sort_keys=True)
 
@@ -296,8 +295,6 @@ def load_plan(path) -> SavedPlan:
         val = doc["tables"][field]
         if field in _INT_FIELDS:
             setattr(tabs, field, int(val))
-        elif field == "forward_steps":
-            setattr(tabs, field, tuple(int(x) for x in val))
         else:
             dtype = bool if field in _BOOL_TABLES else np.int32
             setattr(tabs, field, np.asarray(val, dtype=dtype))

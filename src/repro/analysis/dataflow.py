@@ -97,7 +97,7 @@ class Violation:
     check: str                    # one of CHECKS
     detail: str
     device: int | None = None
-    step: int | None = None      # compressed forward step index
+    step: int | None = None      # forward program tick
     slot: int | None = None      # buffer slot / ring channel context
 
     def __str__(self) -> str:

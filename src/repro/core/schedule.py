@@ -371,6 +371,39 @@ def validate_schedule(
 # Planner-side communication statistics (liveness windows + overlap slack)
 # --------------------------------------------------------------------------
 
+def forward_ticks(sched: Schedule) -> dict[tuple[int, int], int]:
+    """Time a schedule's forward placements by their dependencies.
+
+    The executors run only the forward placements (virtual < S) as a
+    lockstep scan of *ticks*; the backward pass is that scan's transpose.
+    A schedule's global steps interleave B placements with the F ones, so
+    keeping each F placement at its step leaves idle ticks wherever the
+    schedule put a backward task.  Instead, each device's forward
+    placements run in the schedule's own per-device order, each at the
+    earliest tick at which its device is free (one task per device per
+    tick) and its producer ``(virtual - 1, mb)`` ran at least one tick
+    before (a ring hop and the local turnaround both deliver one tick
+    later).  This is the least timing that keeps every dependency and
+    every device's order, so it is never longer than the schedule's
+    forward steps and equals them where no B slot left a gap.
+
+    Returns ``{(virtual, microbatch): tick}``.  Assumes a schedule whose
+    producers run at earlier steps than their consumers (constraint
+    (10)); a producer at a later step does not constrain its consumer.
+    """
+    free: dict[int, int] = {}               # device -> first free tick
+    tick: dict[tuple[int, int], int] = {}
+    for p in sorted((p for p in sched.placements if p.virtual < sched.S),
+                    key=lambda p: (p.step, p.device)):
+        k = free.get(p.device, 0)
+        prod = tick.get((p.virtual - 1, p.microbatch))
+        if prod is not None:
+            k = max(k, prod + 1)
+        tick[(p.virtual, p.microbatch)] = k
+        free[p.device] = k + 1
+    return tick
+
+
 @dataclasses.dataclass(frozen=True)
 class ScheduleCommStats:
     """Ring-message accounting of a schedule's forward placements.
@@ -420,19 +453,18 @@ def comm_stats(sched: Schedule, device_of_stage: Callable[[int], int],
                folded: bool) -> ScheduleCommStats:
     """Compute :class:`ScheduleCommStats` for a valid schedule.
 
-    Uses the same message model as the executor lowering: an enc->enc
-    boundary rides the down ring, dec->dec the up ring; a message is live
-    in its receiver's buffer from the step after its producer until its
-    consumer runs; the turnaround and the (conservative, all-slots) skip
-    stash are device-local lifetimes.  Windows are max-overlap counts per
-    device, so they equal the first-fit coloring's slot counts.
+    Uses the same timing (:func:`forward_ticks`) and message model as the
+    executor lowering: an enc->enc boundary rides the down ring, dec->dec
+    the up ring; a message is live in its receiver's buffer from the tick
+    after its producer until its consumer runs; the turnaround and the
+    (conservative, all-slots) skip stash are device-local lifetimes.
+    Windows are max-overlap counts per device, so they equal the first-fit
+    coloring's slot counts.
     """
     S, M = sched.S, sched.M
     half = S // 2 if folded else S
     fwd = [p for p in sched.placements if p.virtual < S]
-    steps = sorted({p.step for p in fwd})
-    k_of_step = {t: k for k, t in enumerate(steps)}
-    k_of = {(p.virtual, p.microbatch): k_of_step[p.step] for p in fwd}
+    k_of = forward_ticks(sched)
 
     def peak(ivs_by_dev: dict[int, list[tuple[int, int]]]) -> int:
         best = 0

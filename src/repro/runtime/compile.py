@@ -674,6 +674,14 @@ class CompiledPipeline:
             lines.append(
                 f"  comm: {mode}, exposed hops {tabs.exposed_hops} / "
                 f"hidden {tabs.hidden_hops} (of {live_d + live_u} live)")
+            sched_steps = len({p.step for p in sched.placements
+                               if p.virtual < sched.S})
+            lines.append(
+                f"  forward program: {tabs.num_steps} ticks ({sched_steps} "
+                "schedule steps with a forward placement), idle ticks per "
+                "device "
+                + ",".join(str(tabs.num_steps - r)
+                           for r in tabs.running_steps))
             lines.append(
                 "  weight rows: running steps per device "
                 f"{','.join(map(str, tabs.running_steps))} of "

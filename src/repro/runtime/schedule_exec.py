@@ -13,7 +13,12 @@ invisible.  This module makes the Schedule the single source of truth:
    S = 2VD plan) runs at each step, on which microbatch, which receive
    slot the incoming boundary activation lands in, whether the slot
    embeds / reads / writes the turnaround buffer, and when to emit the
-   loss.  Every cross-device dependency is checked against the
+   loss.  The program's steps (*ticks*) come from the dependencies, not
+   from the schedule's slots: each device runs its F placements in the
+   schedule's order, each as soon as its input has arrived
+   (``core.schedule.forward_ticks``), so the slots the schedule gave to
+   B placements — which run later, in the transposed scan — leave no
+   idle tick.  Every cross-device dependency is checked against the
    synchronous-scan dataflow at lowering time — a schedule the executor
    could not realize raises ``ValueError`` here instead of silently
    computing garbage.  Pass the stage->device mapping as a ``devices``
@@ -27,7 +32,7 @@ invisible.  This module makes the Schedule the single source of truth:
    per step and the rings wrap so interleaved slot boundaries cross
    device D-1 -> 0.  Any *valid* schedule — including ILP schedules whose
    step timing differs from the greedy templates, and interleaved V > 1
-   plans — executes exactly as synthesized.
+   plans — executes in the per-device order it was synthesized with.
 
 Backward placements (virtual stage >= S) are realized by JAX autodiff as
 the transposed scan, mirroring the forward order — the same convention as
@@ -93,8 +98,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.schedule import (Schedule, placement_bounds_error,
-                                 slot_maps)
+from repro.core.schedule import (Schedule, forward_ticks,
+                                 placement_bounds_error, slot_maps)
 from repro.runtime import scopes
 from repro.runtime.pipeline import (WIRE_DTYPES, PipelineConfig,
                                     _wrap_remat, ring_perms, tree_index,
@@ -171,12 +176,18 @@ def _color_intervals(ivs) -> tuple[dict[tuple[int, int], int], int]:
 class StepTables:
     """Per-device forward step programs + message routing for one Schedule.
 
-    All arrays are ``[D, num_steps]`` over the *compressed forward step
-    axis*: the schedule's global steps that contain at least one forward
-    placement, in order (``forward_steps`` maps compressed index -> global
-    step).  Compression preserves the relative order of every placement, so
-    the synchronous scan (one ppermute hop per step) realizes the same
-    partial order the schedule was validated against.
+    All arrays are ``[D, num_steps]`` over the forward program's *ticks*
+    (:func:`~repro.core.schedule.forward_ticks`): each device runs its
+    forward placements in the schedule's per-device order, each at the
+    earliest tick at which the device is free and its input has arrived
+    (one tick after its producer, over a hop or through the turnaround).
+    The ticks are not the schedule's steps: the schedule interleaves each
+    microbatch's B placements with the F ones, but the executor runs the
+    backward pass as the transposed scan, so a tick kept for a B slot
+    would idle every device in both scans.  The timing keeps every
+    dependency and every device's order, so the synchronous scan (one
+    ppermute hop per tick) realizes the partial order the schedule was
+    validated against, in the fewest ticks that order allows.
 
     - ``sel``: ``IDLE`` / ``RUN_ENC`` / ``RUN_DEC`` (linear pipelines only
       use ``IDLE`` / ``RUN_ENC``).
@@ -219,7 +230,6 @@ class StepTables:
     M: int
     V: int
     rings: int                     # 2 folded (down + up), 1 linear
-    forward_steps: tuple[int, ...]
     sel: np.ndarray
     slot: np.ndarray
     mb: np.ndarray
@@ -364,9 +374,8 @@ class StepTables:
                     check="program-shape")
         fwd = sorted((p for p in sched.placements if p.virtual < S),
                      key=lambda p: (p.step, p.device))
-        steps = sorted({p.step for p in fwd})
-        k_of_step = {t: k for k, t in enumerate(steps)}
-        T = len(steps)
+        k_of_task = forward_ticks(sched)
+        T = 1 + max(k_of_task.values(), default=-1)
 
         sel = np.zeros((D, T), dtype=np.int32)
         slot = np.zeros((D, T), dtype=np.int32)
@@ -402,7 +411,7 @@ class StepTables:
         enc_runs: list[tuple[int, int, int, int]] = []  # (dev, k, m, vslot)
         dec_runs: list[tuple[int, int, int, int]] = []
 
-        k_of_task: dict[tuple[int, int], int] = {}
+        booked: set[tuple[int, int]] = set()       # (device, step)
         for p in fwd:
             v, m, dev = p.virtual, p.microbatch, p.device
             err = placement_bounds_error(p, S, M, D)
@@ -424,13 +433,13 @@ class StepTables:
                     f"{enc_slot.get(v, dec_slot.get(v))}); re-synthesize "
                     "the schedule with the partition's device_of_stage",
                     check="stage-routing", device=dev)
-            k = k_of_step[p.step]
-            if sel[dev, k] != IDLE:
+            k = k_of_task[(v, m)]
+            if (dev, p.step) in booked:
                 raise PlanError(
                     f"device {dev} double-booked at step {p.step} — run "
                     "validate_schedule",
                     check="program-shape", device=dev, step=k)
-            k_of_task[(v, m)] = k
+            booked.add((dev, p.step))
             mb[dev, k] = m
             is_enc = v < half
             sel[dev, k] = RUN_ENC if is_enc else RUN_DEC
@@ -477,18 +486,20 @@ class StepTables:
                 loss[dev, k] = True
 
         # Dataflow feasibility: each forward task's input must have been
-        # produced at an earlier compressed step (so it arrived — one
-        # ppermute hop — at or before the consumer's step).
+        # produced at an earlier schedule step.  The ticks then keep every
+        # dependency (the input arrives — one ppermute hop — at or before
+        # the consumer's tick) only because the schedule's order does.
+        step_of = {(p.virtual, p.microbatch): p.step for p in fwd}
         for p in fwd:
             if p.virtual == 0:
                 continue
             dep = (p.virtual - 1, p.microbatch)
-            if dep not in k_of_task:
+            if dep not in step_of:
                 raise PlanError(
                     f"task v={p.virtual} m={p.microbatch} has no scheduled "
                     "predecessor — run validate_schedule",
                     check="matched-store-read", device=p.device)
-            if k_of_task[(p.virtual, p.microbatch)] < k_of_task[dep] + 1:
+            if p.step < step_of[dep] + 1:
                 raise PlanError(
                     f"task v={p.virtual} m={p.microbatch} runs before its "
                     "input can arrive (constraint (10)) — run "
@@ -590,8 +601,7 @@ class StepTables:
             if skip_wr[dev, k]:
                 skip_wr_slot[dev, k] = entry_slot[(dev, m, vslot)]
 
-        return cls(D=D, M=M, V=V, rings=2 if folded else 1,
-                   forward_steps=tuple(steps), sel=sel,
+        return cls(D=D, M=M, V=V, rings=2 if folded else 1, sel=sel,
                    slot=slot, mb=mb,
                    down_mb=down_mb, down_valid=down_valid, up_mb=up_mb,
                    up_valid=up_valid, loss=loss, embed=embed,

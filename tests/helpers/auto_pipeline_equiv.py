@@ -23,6 +23,8 @@ Configs (pass names as argv to run a subset; default: all):
   wave-even      UViT, S=2D (D=2), uniform costs -> even folded wave
   wave-uneven    UViT, S=2D (D=2), heterogeneous times -> uneven symmetric
                  cuts from the bidirectional DP (Algorithm 1)
+  wave-p4-m8     UViT, D=4, M=8: the forward program re-timed by its
+                 dependencies (22 ticks for 28 schedule steps)
   wave-short     UViT, D=4, M=D-1: the closed-form wave executor must
                  refuse (stale-row clip), the table executor must match ref
   wave-ilp       UViT, D=2, ILP-synthesized schedule through the
@@ -97,8 +99,11 @@ def _check_tables_match_grid(cp, label):
     assert_programs_match_grid(cp.schedule)
     tabs = assert_step_tables_match_grid(cp.schedule, cp.folded)
     n_fwd = int((tabs.sel != 0).sum())
+    f_steps = len({p.step for p in cp.schedule.placements
+                   if p.virtual < cp.schedule.S})
     print(f"{label}: step tables == grid "
-          f"({n_fwd} forward slots over {tabs.num_steps} steps)")
+          f"({n_fwd} forward slots over {tabs.num_steps} ticks, "
+          f"{f_steps} schedule steps)")
 
 
 def _check_windows(cp, label):
@@ -443,6 +448,12 @@ CONFIGS = {
     "wave-lm-uneven": lambda: _run_lm(
         "wave-lm-uneven", [4, 1, 1, 1, 1, 1, 1, 4], True,
         force_wave=True, pipeline_devices=2),
+    # the four-chip cells' plan (P=4, M=8) at a tiny width: the forward
+    # program runs in 22 ticks where the schedule holds its F placements
+    # on 28 steps; loss and grads still match the reference and the
+    # closed-form wave on the exact fp32 wire
+    "wave-p4-m8": lambda: _run_uvit(
+        "wave-p4-m8", None, False, pipeline_devices=4, microbatches=8),
     # M = D - 1: only the table-driven lowering can run this; the
     # closed-form executor must reject it (stale-row clip)
     "wave-short": lambda: _run_uvit(

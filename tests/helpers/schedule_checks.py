@@ -5,6 +5,7 @@ slot-for-slot" contract, used by the in-process planning tests
 (test_schedule, test_auto_pipeline) and the multi-device subprocess
 equivalence helper (auto_pipeline_equiv).
 """
+from repro.core.schedule import forward_ticks
 from repro.runtime.schedule_exec import IDLE, RUN_DEC, RUN_ENC, StepTables
 
 
@@ -30,23 +31,26 @@ def assert_programs_match_grid(sched):
 
 def assert_step_tables_match_grid(sched, folded, device_of_stage=None):
     """The executor-facing ``StepTables`` cover exactly the schedule's
-    forward placements, with the right selector/microbatch/slot per step
-    (the enc/dec boundary is S/2 — a device may hold V slots per kind)."""
+    forward placements, each at its tick (``forward_ticks``) with the
+    right selector/microbatch/slot (the enc/dec boundary is S/2 — a device
+    may hold V slots per kind), and each device runs them in the order
+    the schedule gives them."""
     tabs = StepTables.from_schedule(sched, folded=folded,
                                     device_of_stage=device_of_stage)
-    grid = sched.grid()
+    ticks = forward_ticks(sched)
     S = sched.S
     half = S // 2 if folded else S
-    for k, t in enumerate(tabs.forward_steps):
-        for d in range(sched.D):
-            p = grid[d][t]
-            if p is not None and p.virtual < S:
-                want = RUN_DEC if folded and p.virtual >= half else RUN_ENC
-                assert tabs.sel[d, k] == want, (d, k)
-                assert tabs.mb[d, k] == p.microbatch, (d, k)
-                assert 0 <= tabs.slot[d, k] < tabs.V, (d, k)
-            else:
-                assert tabs.sel[d, k] == IDLE, (d, k)
-    n_fwd = sum(1 for p in sched.placements if p.virtual < S)
-    assert int((tabs.sel != IDLE).sum()) == n_fwd
+    fwd = [p for p in sched.placements if p.virtual < S]
+    for p in fwd:
+        d, k = p.device, ticks[(p.virtual, p.microbatch)]
+        want = RUN_DEC if folded and p.virtual >= half else RUN_ENC
+        assert tabs.sel[d, k] == want, (d, k)
+        assert tabs.mb[d, k] == p.microbatch, (d, k)
+        assert 0 <= tabs.slot[d, k] < tabs.V, (d, k)
+    assert int((tabs.sel != IDLE).sum()) == len(fwd)
+    for d in range(sched.D):
+        mine = sorted((p for p in fwd if p.device == d),
+                      key=lambda p: p.step)
+        assert [ticks[(p.virtual, p.microbatch)] for p in mine] == sorted(
+            ticks[(p.virtual, p.microbatch)] for p in mine), d
     return tabs

@@ -17,7 +17,8 @@ import pytest
 from helpers import run_helper
 
 from repro.core.partition import partition
-from repro.core.schedule import schedule_for_partition, validate_schedule
+from repro.core.schedule import (forward_ticks, schedule_for_partition,
+                                 validate_schedule)
 from repro.core.tuner import tune
 from repro.models.diffusion import UViTConfig, uvit_pipeline_graph
 from repro.models.layers import AttnConfig
@@ -150,9 +151,12 @@ def test_device_programs_match_grid():
     ):
         assert_programs_match_grid(cp.schedule)
         tabs = assert_step_tables_match_grid(cp.schedule, cp.folded)
-        assert all(p.step in tabs.forward_steps
-                   for p in cp.schedule.placements
-                   if p.virtual < cp.schedule.S)
+        # the forward program ends at its last tick, and is never longer
+        # than the schedule's steps that hold a forward placement
+        ticks = forward_ticks(cp.schedule)
+        f_steps = {p.step for p in cp.schedule.placements
+                   if p.virtual < cp.schedule.S}
+        assert tabs.num_steps == 1 + max(ticks.values()) <= len(f_steps)
 
 
 def test_step_tables_reject_infeasible_schedule():
@@ -520,8 +524,8 @@ def test_step_tables_memoized_lowering():
 # differential executor tests (subprocess, mocked multi-device mesh)
 # ---------------------------------------------------------------------------
 
-_TIER1_EQUIV = ("linear-uneven", "wave-uneven", "wave-short",
-                "wave-asym", "wave-sparse", "wave-interleaved",
+_TIER1_EQUIV = ("linear-uneven", "wave-uneven", "wave-p4-m8",
+                "wave-short", "wave-asym", "wave-sparse", "wave-interleaved",
                 "linear-zero2", "wave-zero1", "wave-zero2")
 
 
@@ -544,6 +548,19 @@ def test_auto_pipeline_equivalence_uneven_and_short(tier1_equiv_out):
     for cfg in ("linear-uneven", "wave-uneven", "wave-short"):
         assert f"{cfg}: " in tier1_equiv_out and "grads OK" in tier1_equiv_out
     assert "closed-form executor rejects M < D" in tier1_equiv_out
+
+
+def test_auto_pipeline_equivalence_retimed_forward_program(
+        tier1_equiv_out):
+    """The four-chip cells' plan (P=4, M=8): the executor runs the
+    schedule's forward placements in 22 ticks instead of the 28 steps the
+    schedule holds them on, and its loss and gradients still match the
+    single-device reference and the closed-form wave (fp32 wire, rtol
+    1e-4)."""
+    assert ("wave-p4-m8: step tables == grid (64 forward slots over 22 "
+            "ticks, 28 schedule steps)") in tier1_equiv_out
+    assert "wave-p4-m8: table executor == closed-form" in tier1_equiv_out
+    assert "wave-p4-m8: counts=" in tier1_equiv_out
 
 
 def test_auto_pipeline_equivalence_asymmetric_folds(tier1_equiv_out):

@@ -4,11 +4,12 @@ The lowering (``StepTables.from_schedule``) derives, by first-fit interval
 coloring, the rotating-buffer windows W_down/W_up/W_turn/W_skip and the
 per-step ring-activity masks the executors lower.  These property tests
 cross-check every window against an INDEPENDENT event-driven replay of the
-schedule (a message is in flight from the step after its producer runs
+schedule (a message is in flight from the tick after its producer runs
 until its consumer runs, inclusive; a stash entry from its write until its
-last read), across random valid schedules: greedy, duration-aware timed
-greedy in all priority orientations, and (nightly) the exact ILP, for
-interleave degrees V in {1, 2, 4}.
+last read; ticks from an independent dependency-driven timing), across
+random valid schedules: greedy, duration-aware timed greedy in all
+priority orientations, and (nightly) the exact ILP, for interleave
+degrees V in {1, 2, 4}.
 
 They also hold the planner and the executor to AGREE on the overlap
 accounting: the planner-side ``core.schedule.comm_stats`` must report the
@@ -30,20 +31,41 @@ from repro.core.schedule import (TIMED_PRIORITIES, comm_stats,
 from repro.runtime.schedule_exec import StepTables
 
 
+def asap_ticks(sched):
+    """Independent timing oracle: every forward task's tick relaxed to the
+    fixpoint of ``tick = max(device predecessor + 1, producer + 1)``, each
+    device's tasks in the schedule's step order (a longest-path sweep, not
+    the lowering's single pass in step order)."""
+    fwd = [p for p in sched.placements if p.virtual < sched.S]
+    order = {}
+    for p in sorted(fwd, key=lambda p: p.step):
+        order.setdefault(p.device, []).append((p.virtual, p.microbatch))
+    tick = {(p.virtual, p.microbatch): 0 for p in fwd}
+    changed = True
+    while changed:
+        changed = False
+        for tasks in order.values():
+            for i, (v, m) in enumerate(tasks):
+                need = max(tick[tasks[i - 1]] + 1 if i else 0,
+                           tick[(v - 1, m)] + 1 if v else 0)
+                if need > tick[(v, m)]:
+                    tick[(v, m)] = need
+                    changed = True
+    return tick
+
+
 def replay_windows(sched, device_of_stage, folded):
     """Event-driven reference: max simultaneously-live entries per buffer.
 
-    Deliberately brute force (per-step overlap counting, no coloring) so a
-    bug in the lowering's interval analysis cannot hide in a shared
-    implementation.
+    Deliberately brute force (per-tick overlap counting, no coloring, its
+    own timing oracle) so a bug in the lowering's interval analysis cannot
+    hide in a shared implementation.
     """
     S = sched.S
     half = S // 2 if folded else S
     fwd = [p for p in sched.placements if p.virtual < S]
-    steps = sorted({p.step for p in fwd})
-    k_of_step = {t: k for k, t in enumerate(steps)}
-    k_of = {(p.virtual, p.microbatch): k_of_step[p.step] for p in fwd}
-    T = len(steps)
+    k_of = asap_ticks(sched)
+    T = 1 + max(k_of.values())
 
     def peak(intervals_by_dev):
         best = 0
@@ -99,13 +121,14 @@ def replay_windows(sched, device_of_stage, folded):
     return {"W_down": peak(rings["down"]), "W_up": peak(rings["up"]),
             "W_turn": peak(turn), "W_skip": peak(skip),
             "n_down": n_msgs["down"], "n_up": n_msgs["up"],
-            "x_down": n_exposed["down"], "x_up": n_exposed["up"]}
+            "x_down": n_exposed["down"], "x_up": n_exposed["up"], "T": T}
 
 
 def _check(sched, device_of_stage, folded):
     tabs = StepTables.from_schedule(sched, folded=folded,
                                     device_of_stage=device_of_stage)
     ref = replay_windows(sched, device_of_stage, folded)
+    assert tabs.num_steps == ref["T"], (tabs.num_steps, ref["T"])
     assert tabs.W_down == ref["W_down"], (tabs.W_down, ref)
     assert tabs.W_up == ref["W_up"], (tabs.W_up, ref)
     assert tabs.W_turn == ref["W_turn"], (tabs.W_turn, ref)

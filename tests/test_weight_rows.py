@@ -97,7 +97,12 @@ def test_fetch_rows_address_the_running_slot(kind):
             f"{tabs.num_steps}")
     assert line in cp.describe()
     if kind == "wave-p4-m8":
-        assert tabs.num_steps == 28 and tasks == 16
+        # 16 tasks a device in 2M + 2(D-1) = 22 ticks; the schedule's F
+        # placements sit on 28 of its steps
+        assert tabs.num_steps == 22 and tasks == 16
+        assert ("forward program: 22 ticks (28 schedule steps with a "
+                "forward placement), idle ticks per device 6,6,6,6"
+                in cp.describe())
 
 
 # ---------------------------------------------------------------------------
